@@ -31,6 +31,9 @@ def main() -> None:
                     help="install a process-wide metrics registry and "
                          "write its Prometheus exposition to PATH")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     tele = None
     reg = None

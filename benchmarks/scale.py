@@ -244,6 +244,9 @@ def main(argv: Optional[List[str]] = None) -> None:
                     help="write the batched runs' telemetry JSONL trace")
     ap.add_argument("--latency-tol", type=float, default=2.0)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.check:
         fails = check_acceptance(args.reps)

@@ -10,17 +10,14 @@ IPW aggregation.  Compare --scheme proposed vs baseline1..baseline4.
 import argparse
 import json
 import sys
-import types
 
 import jax
 import numpy as np
 
 from repro import obs
-from repro.core import default_system
-from repro.data import SyntheticImages, non_iid_split
-from repro.fed import (CHAOS_SPEC, FEELConfig, FEELTrainer, FaultSpec,
-                       ResilienceConfig)
-from repro.models import cnn
+from repro.fed import (CHAOS_SPEC, FEELTrainer, FaultSpec, ResilienceConfig,
+                       paper_setup)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def parse_faults(arg):
@@ -74,20 +71,12 @@ def main():
                          "fresh trainer and assert bit-identical params "
                          "(exits non-zero on mismatch)")
     args = ap.parse_args()
+    enable_compile_cache()
     faults = parse_faults(args.faults)
 
-    train = SyntheticImages.make(6000, side=args.side, seed=0)
-    test = SyntheticImages.make(1500, side=args.side, seed=1)
-    data = non_iid_split(train, test, K=10, per_device=600,
-                         mislabel_prop=args.mislabel, seed=0)
-    sys_ = default_system(K=10, N=5, Q=2, D_hat=args.d_hat)
-    cfg = FEELConfig(scheme=args.scheme, d_hat=args.d_hat,
-                     selection_method=args.selection, eval_every=10)
-    cc = cnn.CNNConfig(side=args.side)
-    params = cnn.init(jax.random.PRNGKey(0), cc)
-    model = types.SimpleNamespace(features=cnn.features, apply=cnn.apply,
-                                  loss_fn=cnn.loss_fn,
-                                  accuracy=cnn.accuracy)
+    sys_, data, model, params, cfg = paper_setup(
+        side=args.side, d_hat=args.d_hat, scheme=args.scheme,
+        selection=args.selection, mislabel=args.mislabel)
     tele = None
     if args.trace:
         tele = obs.Telemetry(path=args.trace,
@@ -109,8 +98,7 @@ def main():
                                       checkpoint_dir=args.checkpoint_dir)
 
     def make_trainer(res=resilience, quiet=False):
-        p0 = cnn.init(jax.random.PRNGKey(0), cc)
-        return FEELTrainer(sys_, data, model, p0, cfg,
+        return FEELTrainer(sys_, data, model, params, cfg,
                            telemetry=None if quiet else tele,
                            monitor=None if quiet else monitor,
                            faults=faults, resilience=res)

@@ -137,9 +137,11 @@ def proposed_scheme(sys: SystemParams, state: RoundState,
     solve to fail so the fallback chain runs (chaos testing).  The
     chain — CCP power failure -> closed-form evaluator, failed/
     infeasible matching -> greedy feasible baseline — also catches
-    *natural* failures: a solver exception degrades instead of
-    propagating, and every degradation is recorded as a ``fault`` trace
-    event plus ``feel_fallbacks_total``.
+    *natural* failures when the resilience layer is on (``faults``
+    given or ``repair_infeasible`` set): a solver exception then
+    degrades instead of propagating, and every degradation is recorded
+    as a ``fault`` trace event plus ``feel_fallbacks_total``.  With the
+    layer off, a natural solver exception propagates.
 
     ``repair_infeasible``: additionally route *naturally infeasible*
     (but non-crashing) matchings through the greedy fallback when that
@@ -179,7 +181,9 @@ def proposed_scheme(sys: SystemParams, state: RoundState,
                 mode=(matching_mode if evaluator == "closed_form"
                       else "auto"),
                 telemetry=tele)
-        except Exception as e:  # degrade, don't die
+        except Exception as e:
+            if faults is None and not repair_infeasible:
+                raise  # resilience layer off: a solver error is fatal
             matching_reason = type(e).__name__
             tele.fault("solver_fail", injected=False, solver="matching",
                        reason=matching_reason)

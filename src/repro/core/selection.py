@@ -113,10 +113,27 @@ def gradient_projection(sys: SystemParams, sigma: Array, mask: Array,
     if device_chunk and device_chunk < sigma.shape[0]:
         return _gp_chunked(sys, sigma, mask, steps, step0, init,
                            device_chunk)
+    return _gp_rows(sigma, mask, init, sys.a_weights(), sys.q, sys.lam,
+                    steps, step0)
+
+
+def _gp_rows(sigma: Array, mask: Array, init: Array, A: Array, q: Array,
+             lam: Array, steps: int, step0: float) -> Array:
+    """Algorithm 4 on a block of devices (rows), given their A_k and q_k.
+
+    The objective is the delta-dependent part of Problem 4
+    (``delta_mod.selection_only_objective``) with the A_k weights, which
+    carry the global |D̂| total, passed in: every operation is row-wise,
+    so a block's iterates equal those rows of the full-matrix run.
+    """
 
     def f(d):
         # C^com/C^cmp are constants w.r.t. delta; argmin is unchanged.
-        return delta_mod.selection_only_objective(sys, d * mask, sigma)
+        dm = d * mask
+        n_sel = jnp.sum(dm, axis=1)
+        mean = (jnp.sum(dm * sigma, axis=1)
+                / jnp.maximum(n_sel, delta_mod._EPSDIV))
+        return lam * jnp.sum(A * mean) - (1.0 - lam) * jnp.sum(q * n_sel)
 
     grad_f = jax.grad(f)
 
@@ -136,17 +153,9 @@ def gradient_projection(sys: SystemParams, sigma: Array, mask: Array,
 
 def _gp_chunked(sys: SystemParams, sigma: Array, mask: Array, steps: int,
                 step0: float, init: Array, chunk: int) -> Array:
-    """Algorithm 4 over device blocks under one ``lax.map``.
-
-    The per-chunk objective is the Problem-4 selection term restricted
-    to the block, with the A_k weights (which carry the global |D̂|
-    total) precomputed once — so the block gradients, normalization and
-    projection are the same row-wise operations as the full-matrix
-    path, and the iterates match it device for device.
-    """
+    """Algorithm 4 over device blocks of ``_gp_rows`` under one
+    ``lax.map``; the A_k weights are computed once for all devices."""
     K, J = sigma.shape
-    lam = sys.lam
-    A = sys.a_weights()
     pad = (-K) % chunk
 
     def padk(x):
@@ -161,28 +170,10 @@ def _gp_chunked(sys: SystemParams, sigma: Array, mask: Array, steps: int,
 
     def run_block(args):
         sig, msk, ini, A_b, q_b = args
+        return _gp_rows(sig, msk, ini, A_b, q_b, sys.lam, steps, step0)
 
-        def f(d):
-            dm = d * msk
-            mean = (jnp.sum(dm * sig, axis=1)
-                    / jnp.maximum(jnp.sum(dm, axis=1), delta_mod._EPSDIV))
-            return (lam * jnp.sum(A_b * mean)
-                    - (1.0 - lam) * jnp.sum(q_b * jnp.sum(dm, axis=1)))
-
-        grad_f = jax.grad(f)
-
-        def body(v, d):
-            step = step0 / (1.0 + v) ** 0.6
-            g = grad_f(d)
-            g = jnp.where(jnp.isfinite(g), g, 0.0)
-            norm = jnp.max(jnp.abs(g), axis=1, keepdims=True)
-            g = g / jnp.maximum(norm, 1e-12)
-            return project_feasible(d - step * g, msk)
-
-        return jax.lax.fori_loop(0, steps, body, ini * msk)
-
-    out = jax.lax.map(run_block, (blocks(sigma), blocks(mask),
-                                  blocks(init), blocks(A), blocks(sys.q)))
+    out = jax.lax.map(run_block, (blocks(sigma), blocks(mask), blocks(init),
+                                  blocks(sys.a_weights()), blocks(sys.q)))
     return out.reshape(n_blocks * chunk, J)[:K]
 
 

@@ -111,6 +111,7 @@ class RoundMetrics:
     n_retries: int = 0          # straggler retry attempts this round
     skipped_update: bool = False  # no usable upload -> no optimizer step
     fallbacks: tuple = ()       # solver degradations (RoundDecision)
+    feasible: bool = True       # RoundDecision.feasible
 
 
 class FEELTrainer:
@@ -456,7 +457,8 @@ class FEELTrainer:
                             n_quarantined=n_quarantined,
                             n_retries=n_retries,
                             skipped_update=skipped_update,
-                            fallbacks=dec.fallbacks)
+                            fallbacks=dec.fallbacks,
+                            feasible=bool(dec.feasible))
 
     def _profile_once(self, name: str, stage: str, fn, args, tele,
                       round_i: int) -> None:

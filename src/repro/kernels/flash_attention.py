@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import resolve_interpret
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
@@ -83,20 +85,27 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "block_q", "block_k",
-                                    "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, scale: float | None = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q, k, v: (BH, S, d) — batch and heads pre-merged, MHA layout.
 
-    Sequences are padded to the block size internally; ``interpret``
-    defaults to True because this container is CPU-only (set False on
-    real TPUs).
+    Sequences are padded to the block size internally; ``interpret=None``
+    compiles on a TPU backend and interprets elsewhere.
     """
+    return _flash_attention(q, k, v, causal=causal, scale=scale,
+                            block_q=block_q, block_k=block_k,
+                            interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("causal", "block_q", "block_k",
+                                    "interpret"))
+def _flash_attention(q, k, v, causal: bool, scale: float | None,
+                     block_q: int, block_k: int,
+                     interpret: bool) -> jax.Array:
     BH, S, d = q.shape
     scale = d ** -0.5 if scale is None else scale
     nq = -(-S // block_q)

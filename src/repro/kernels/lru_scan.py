@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import resolve_interpret
+
 DEFAULT_BLOCK_SEQ = 256
 DEFAULT_BLOCK_CH = 256
 
@@ -52,13 +54,19 @@ def _scan_kernel(a_ref, b_ref, o_ref, h_ref, *, block_seq: int):
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_seq", "block_ch",
-                                             "interpret"))
 def lru_scan(a: jax.Array, b: jax.Array,
              block_seq: int = DEFAULT_BLOCK_SEQ,
              block_ch: int = DEFAULT_BLOCK_CH,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     """a, b: (B, S, C) -> h: (B, S, C) with h_t = a_t h_{t-1} + b_t."""
+    return _lru_scan(a, b, block_seq=block_seq, block_ch=block_ch,
+                     interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("block_seq", "block_ch",
+                                             "interpret"))
+def _lru_scan(a: jax.Array, b: jax.Array, block_seq: int, block_ch: int,
+              interpret: bool) -> jax.Array:
     B, S, C = a.shape
     bs = min(block_seq, S)
     bc = min(block_ch, max(128, C))

@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``use_pallas`` flags on model configs route hot paths through these on
-real TPUs (interpret=False); the CPU container always validates with
-interpret=True against kernels/ref.py.
+``interpret=None`` (the default) compiles on a TPU backend and runs the
+Pallas interpreter elsewhere (``kernels.resolve_interpret``); the tests
+validate interpret mode against kernels/ref.py.
 """
 from __future__ import annotations
 
@@ -17,7 +17,7 @@ from .lru_scan import lru_scan as _lru_scan
 
 def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array,
                          causal: bool = True,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """q,k,v: (B, S, H, d) MHA layout -> (B, S, H, d).
 
     GQA callers should broadcast kv heads first (the kernel is
@@ -35,7 +35,7 @@ lru_scan = _lru_scan
 
 
 def sigma_from_head(h: jax.Array, logits: jax.Array, labels: jax.Array,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """Exact last-layer sigma from features + logits (fused path).
 
     h: (N, d) penultimate features; logits: (N, V); labels: (N,).

@@ -1,8 +1,8 @@
 """Distribution & launch layer.
 
-NOTE: ``dryrun`` must be imported/run as the entry module
-(``python -m repro.launch.dryrun``) so its XLA_FLAGS line executes
-before jax initializes devices; do not import it from here.
+``python -m repro.launch.dryrun`` forces 512 host devices through
+XLA_FLAGS in its ``main``, before jax initializes its backends;
+importing the module changes nothing.
 """
 from .mesh import (data_axes, data_size, make_host_mesh,
                    make_production_mesh, model_size)

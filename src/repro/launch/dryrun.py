@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry run: lower + compile every (arch x shape) on the
 production meshes, extract cost/memory/collective analyses, and append
 one JSON record per combination to experiments/dryrun.jsonl.
@@ -10,11 +7,13 @@ Usage:
     python -m repro.launch.dryrun --all                 # single-pod sweep
     python -m repro.launch.dryrun --all --multi-pod     # 512-chip sweep
 
-The XLA_FLAGS line above MUST stay the first statement: jax locks the
-device count on first init.  Nothing else in the repo sets it.
+``main`` appends --xla_force_host_platform_device_count=512 to
+XLA_FLAGS before jax initializes its backends (jax fixes the device
+count then); importing the module leaves XLA_FLAGS alone.
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -217,6 +216,9 @@ def run_one(arch: str, shape: str, multi_pod: bool, feel: bool = True,
 
 
 def main():
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512")))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS + ["all"], default=None)
     ap.add_argument("--shape", choices=list(SHAPES) + ["all"], default=None)

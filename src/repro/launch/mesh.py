@@ -4,8 +4,8 @@ Single pod: (16, 16) = ("data", "model") — 256 TPU v5e chips.
 Multi-pod: (2, 16, 16) = ("pod", "data", "model") — 512 chips.
 
 Defined as a FUNCTION so importing this module never touches jax
-device state (the dry-run launcher must set XLA_FLAGS before any jax
-initialization).
+device state (the dry-run launcher's ``main`` sets XLA_FLAGS before
+jax initializes its backends).
 """
 from __future__ import annotations
 
@@ -24,12 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, found {len(devices)} — "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    try:
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:  # older jax without the devices kwarg
-        import numpy as np
-        return jax.sharding.Mesh(np.asarray(devices[:n]).reshape(shape),
-                                 axes)
+    return jax.make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:

@@ -21,6 +21,7 @@ import numpy as np
 from ..configs import ARCHS, get_config, smoke_config
 from ..data.synthetic import synthetic_lm_batch
 from ..models import FeelIntegration, init_model, make_train_step, param_count
+from .compile_cache import enable_compile_cache
 from .shapes import make_optimizer
 
 
@@ -85,6 +86,7 @@ def main():
     ap.add_argument("--no-feel", action="store_true")
     ap.add_argument("--clients", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
     run(args.arch, args.steps, args.batch, args.seq, args.smoke,
         feel=not args.no_feel, n_clients=args.clients)
 

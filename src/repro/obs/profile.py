@@ -1,8 +1,7 @@
 """Kernel roofline profiling: FLOPs/bytes per jitted function.
 
 ``cost_of`` lowers + compiles a jitted function ahead-of-time and reads
-XLA's ``cost_analysis()`` — HLO FLOPs and bytes accessed — normalizing
-the per-device-list shape some jax versions return.  ``profile_jitted``
+XLA's ``cost_analysis()`` — HLO FLOPs and bytes accessed.  ``profile_jitted``
 wraps that into a ``ProfileEvent`` (schema v2) recorded once per
 (function, input shapes) compilation, stamped with the backend's
 estimated peak FLOP/s so achieved-vs-peak utilization can be computed
@@ -65,14 +64,11 @@ def peak_flops() -> float:
 def cost_of(fn, *args) -> Dict[str, float]:
     """Lower + compile ``fn`` (a ``jax.jit`` callable) on ``args`` and
     return ``{"flops", "bytes_accessed", "compile_s"}`` from XLA's cost
-    analysis.  jax < 0.4.34 returns one dict per device — take the
-    first (SPMD: identical per device)."""
+    analysis."""
     t0 = time.perf_counter()
     compiled = fn.lower(*args).compile()
     compile_s = time.perf_counter() - t0
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
             "compile_s": compile_s}

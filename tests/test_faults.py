@@ -161,6 +161,26 @@ def test_no_faults_no_fallbacks():
     assert dec.unmatched.size == 0
 
 
+@pytest.mark.parametrize("resilient", [False, True])
+def test_natural_matching_error_needs_resilience(monkeypatch, resilient):
+    """A matching exception that no fault plan injected propagates
+    unless the resilience layer is on; only then does greedy take over."""
+    sys_ = default_system(K=6, N=3, Q=2, D_hat=4)
+    st = sample_round(jax.random.PRNGKey(1), sys_)
+
+    def broken(*args, **kwargs):
+        raise FloatingPointError("solver blew up")
+
+    monkeypatch.setattr(joint_mod.matching_mod, "swap_matching", broken)
+    if not resilient:
+        with pytest.raises(FloatingPointError):
+            joint_mod.proposed_scheme(sys_, st, gp_steps=30)
+        return
+    dec = joint_mod.proposed_scheme(sys_, st, gp_steps=30,
+                                    repair_infeasible=True)
+    assert dec.fallbacks == ("matching->greedy",)
+
+
 # ----------------------------------------------------------------------
 # trainer-level: bit identity, chaos determinism, quarantine, resume
 # ----------------------------------------------------------------------

@@ -1,0 +1,83 @@
+"""Compile-only checks against a described TPU v5e chip (no chip needed).
+
+The TPU compiler refuses what interpret mode accepts: block shapes that
+do not match XLA's tiling, too much fast memory, programs that do not
+fit.  These tests compile the round's Pallas kernel and its jitted
+sigma and local-gradient programs at the paper's §VI-A widths for one
+v5e chip, so such a refusal shows up here and not on the chip.
+
+The topology is described inside a module fixture, never at import:
+only one process may hold the TPU library, and every test worker
+imports every test file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import default_system
+from repro.fed import FEELConfig, FEELTrainer
+from repro.kernels import gradnorm
+from repro.models import cnn
+
+# K=10 devices x |D̂_k|=200 samples, the paper round; K=256 for a
+# multi-block row grid.  84 = penultimate CNN width, 10 = classes.
+PAPER_K, PAPER_D_HAT = 10, 200
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without that chip; keep such entries out of it
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("rows,feat", [
+    (PAPER_K * PAPER_D_HAT, 84),   # features h
+    (PAPER_K * PAPER_D_HAT, 10),   # logit residuals p - y
+    (256 * PAPER_D_HAT, 84),       # K=256: many row blocks
+])
+def test_rownorm2_compiles_for_v5e(one_chip, rows, feat):
+    fn = jax.jit(functools.partial(gradnorm.rownorm2, interpret=False))
+    compiled = fn.lower(_spec((rows, feat), jnp.float32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["sigma_all", "local_grads"])
+def test_round_programs_compile_for_v5e(one_chip, monkeypatch, program):
+    # the kernels pick compile-vs-interpret from the default backend,
+    # which is the CPU here: steer it to the TPU branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cc = cnn.CNNConfig()
+    params = cnn.init(jax.random.PRNGKey(0), cc)
+    cfg = FEELConfig(sigma_method="last_layer_kernel", d_hat=PAPER_D_HAT)
+    sys_ = default_system(K=PAPER_K, N=5, Q=2, D_hat=PAPER_D_HAT)
+    tr = FEELTrainer(sys_, None, cnn, params, cfg)
+    batch = (PAPER_K, PAPER_D_HAT)
+    args = [jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip),
+                         params),
+            _spec(batch + (cc.side, cc.side), jnp.float32, one_chip),
+            _spec(batch, jnp.int32, one_chip)]
+    if program == "local_grads":
+        args.append(_spec(batch, jnp.float32, one_chip))
+    fn = getattr(tr, f"_{program}")
+    hlo = fn.lower(*args).compile().as_text()
+    assert ("tpu_custom_call" in hlo) == (program == "sigma_all")
