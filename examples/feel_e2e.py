@@ -165,7 +165,8 @@ def main():
         print(f"metrics exposition -> {args.metrics}")
     if args.out:
         with open(args.out, "w") as f:
-            json.dump([m.__dict__ for m in metrics], f)
+            json.dump([{k: v for k, v in m.__dict__.items()
+                        if k != "decision"} for m in metrics], f)
 
 
 if __name__ == "__main__":

@@ -60,21 +60,23 @@ def _finish(sys: SystemParams, rho, p, delta, state: RoundState,
         nc = float(cost_mod.net_cost(sys, rho_j, p_j, n_sel))
         dv = float(delta_mod.delta(sys, delta_j, state.sigma))
         obj = float(sys.lam) * dv + (1.0 - float(sys.lam)) * nc
-    reg = metrics_mod.get_default()
-    if reg.enabled:
-        reg.counter("feel_decisions_total",
-                    "round decisions evaluated (eq. 18 + eq. 26)").inc()
-        reg.gauge("feel_decision_net_cost",
-                  "net cost (eq. 18) of the last round decision").set(nc)
-        reg.gauge("feel_decision_delta_obj",
-                  "Delta_hat (eq. 26) of the last round decision").set(dv)
-    if unmatched is None:
-        unmatched = np.zeros(0, np.int64)
-    return RoundDecision(rho=np.asarray(rho), p=np.asarray(p),
-                         delta=np.asarray(delta), net_cost=nc, delta_obj=dv,
-                         objective=obj, feasible=feasible, swaps=swaps,
-                         unmatched=np.asarray(unmatched, np.int64),
-                         fallbacks=tuple(fallbacks))
+    with tele.span("joint.finish"):
+        reg = metrics_mod.get_default()
+        if reg.enabled:
+            reg.counter("feel_decisions_total",
+                        "round decisions evaluated (eq. 18 + eq. 26)").inc()
+            reg.gauge("feel_decision_net_cost",
+                      "net cost (eq. 18) of the last round decision").set(nc)
+            reg.gauge("feel_decision_delta_obj",
+                      "Delta_hat (eq. 26) of the last round decision").set(dv)
+        if unmatched is None:
+            unmatched = np.zeros(0, np.int64)
+        return RoundDecision(rho=np.asarray(rho), p=np.asarray(p),
+                             delta=np.asarray(delta), net_cost=nc,
+                             delta_obj=dv, objective=obj, feasible=feasible,
+                             swaps=swaps,
+                             unmatched=np.asarray(unmatched, np.int64),
+                             fallbacks=tuple(fallbacks))
 
 
 def _count_injected(kind: str) -> None:

@@ -307,22 +307,24 @@ def swap_matching(sys: SystemParams, h, alpha, evaluator: str = "closed_form",
     vectorized per candidate and always runs scalar.
     """
     tele = obs.resolve(telemetry)
-    h = np.asarray(h, np.float64)
-    alpha = np.asarray(alpha, np.float64)
-    K, N, Q = sys.K, sys.N, sys.Q
-    avail = np.flatnonzero(alpha > 0)
     if mode not in ("auto", "scalar", "batched"):
         raise ValueError(f"unknown matching mode: {mode!r}")
     if mode == "batched" and evaluator != "closed_form":
         raise ValueError("mode='batched' requires evaluator='closed_form' "
                          "(per-candidate CCP solves cannot be vectorized); "
                          "use mode='scalar' or mode='auto'")
-    use_batched = (mode == "batched"
-                   or (mode == "auto" and evaluator == "closed_form"
-                       and avail.size >= AUTO_BATCH_MIN))
-    mode_used = "batched" if use_batched else "scalar"
-    scorer = (_BatchScorer(sys, h) if use_batched
-              else _Scorer(sys, h, alpha, evaluator))
+    # the inputs and the system's constants come to the host here
+    with tele.span("matching.prep"):
+        h = np.asarray(h, np.float64)
+        alpha = np.asarray(alpha, np.float64)
+        K, N, Q = sys.K, sys.N, sys.Q
+        avail = np.flatnonzero(alpha > 0)
+        use_batched = (mode == "batched"
+                       or (mode == "auto" and evaluator == "closed_form"
+                           and avail.size >= AUTO_BATCH_MIN))
+        mode_used = "batched" if use_batched else "scalar"
+        scorer = (_BatchScorer(sys, h) if use_batched
+                  else _Scorer(sys, h, alpha, evaluator))
 
     stage = tele.stage("matching")
     stage.__enter__()

@@ -246,17 +246,19 @@ def solve_selection(sys: SystemParams, sigma: Array, mask: Array,
     else:
         raise ValueError(f"unknown selection method: {method}")
     if tele.enabled or reg.enabled:
-        # one host sync, shared by the trace event and the metrics
-        n_selected = int(jnp.sum(out))
-        if tele.enabled:
-            tele.solver("selection", method=method, gp_steps=gp_steps,
-                        n_selected=n_selected)
-        if reg.enabled:
-            reg.counter("feel_selection_calls_total",
-                        "data-selection solves by method").inc(
-                            1, method=method)
-            reg.counter("feel_selection_gp_steps_total",
-                        "gradient-projection (Alg. 4) steps").inc(gp_steps)
-            reg.counter("feel_selection_selected_total",
-                        "samples selected across rounds").inc(n_selected)
+        with tele.span("telemetry"):
+            # one host sync, shared by the trace event and the metrics
+            n_selected = int(jnp.sum(out))
+            if tele.enabled:
+                tele.solver("selection", method=method, gp_steps=gp_steps,
+                            n_selected=n_selected)
+            if reg.enabled:
+                reg.counter("feel_selection_calls_total",
+                            "data-selection solves by method").inc(
+                                1, method=method)
+                reg.counter("feel_selection_gp_steps_total",
+                            "gradient-projection (Alg. 4) steps").inc(
+                                gp_steps)
+                reg.counter("feel_selection_selected_total",
+                            "samples selected across rounds").inc(n_selected)
     return out

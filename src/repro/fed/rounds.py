@@ -112,6 +112,9 @@ class RoundMetrics:
     skipped_update: bool = False  # no usable upload -> no optimizer step
     fallbacks: tuple = ()       # solver degradations (RoundDecision)
     feasible: bool = True       # RoundDecision.feasible
+    #: the decision the round applied (after a survivor re-solve, the
+    #: re-solved one)
+    decision: Optional[joint_mod.RoundDecision] = None
 
 
 class FEELTrainer:
@@ -222,14 +225,6 @@ class FEELTrainer:
 
             return jax.vmap(one_device)(images, labels, delta)
 
-        if self.obs.annotate:
-            # optional jax.profiler trace annotations: the jitted round
-            # computations show up named in TensorBoard/Perfetto traces
-            sigma_all = obs.annotate_fn(sigma_all, "repro.sigma_all")
-            local_grads = obs.annotate_fn(local_grads, "repro.local_grads")
-            local_deltas = obs.annotate_fn(local_deltas,
-                                           "repro.local_deltas")
-
         self._sigma_all = sigma_all
         self._local_grads = local_grads
         self._local_deltas = local_deltas
@@ -255,7 +250,9 @@ class FEELTrainer:
         # opened below records it as parent, so export/diff/dash can
         # reconstruct the full call hierarchy.  Entered manually — the
         # span must close just before RoundMetrics is built so eval and
-        # aggregation land inside it.
+        # aggregation land inside it.  The round's own glue between the
+        # stages sits in plain spans (round.draws, round.uploads,
+        # round.record, telemetry), so the stages keep their meaning.
         span_round = tele.span("round")
         span_round.__enter__()
         rf = (self.faults.for_round(i, sys.K)
@@ -263,26 +260,32 @@ class FEELTrainer:
 
         with tele.stage("data"):
             images, labels, true = self._gather_round_batches()
-        self.key, kh, ka, kb = jax.random.split(self.key, 4)
+        # the draws' span opens twice: the key split goes before sigma,
+        # where its dispatch overlaps the batch's copy to the device
+        with tele.span("round.draws"):
+            self.key, kh, ka, kb = jax.random.split(self.key, 4)
 
         if tele.profile:
             self._profile_once("sigma_all", "sigma", self._sigma_all,
                                (self.params, images, labels), tele, i)
         with tele.stage("sigma"):
             sigma = tele.block(self._sigma_all(self.params, images, labels))
-        h = jax.random.exponential(kh, (sys.K, sys.N)) * 1e-5
-        alpha = (jax.random.uniform(ka, (sys.K,)) < sys.eps
-                 ).astype(jnp.float32)
-        n_quarantined = 0
-        if self._resilient:
-            # quarantined devices sit the round out *before* the solve,
-            # so no RB/power is allocated to them (skip-with-decay)
-            quarantined = self._quarantined_until > i
-            n_quarantined = int(np.sum(quarantined))
-            if n_quarantined:
-                alpha = alpha * jnp.asarray(~quarantined, jnp.float32)
-        mask = jnp.ones_like(sigma)
-        state = RoundState(h=h, alpha=alpha, sigma=sigma, sigma_mask=mask)
+        with tele.span("round.draws"):
+            h = jax.random.exponential(kh, (sys.K, sys.N)) * 1e-5
+            alpha = (jax.random.uniform(ka, (sys.K,)) < sys.eps
+                     ).astype(jnp.float32)
+            n_quarantined = 0
+            if self._resilient:
+                # quarantined devices sit the round out *before* the
+                # solve, so no RB/power is allocated to them
+                # (skip-with-decay)
+                quarantined = self._quarantined_until > i
+                n_quarantined = int(np.sum(quarantined))
+                if n_quarantined:
+                    alpha = alpha * jnp.asarray(~quarantined, jnp.float32)
+            mask = jnp.ones_like(sigma)
+            state = RoundState(h=h, alpha=alpha, sigma=sigma,
+                               sigma_mask=mask)
 
         if cfg.scheme == "proposed" and i < cfg.warmup_rounds:
             # warmup: resource allocation as proposed, selection = all
@@ -314,9 +317,10 @@ class FEELTrainer:
         else:
             raise ValueError(cfg.scheme)
 
-        delta = jnp.asarray(dec.delta)
-        matched = jnp.asarray(dec.rho.sum(axis=1) > 0, jnp.float32)
-        uploaded = alpha * matched
+        with tele.span("round.uploads"):
+            delta = jnp.asarray(dec.delta)
+            matched = jnp.asarray(dec.rho.sum(axis=1) > 0, jnp.float32)
+            uploaded = alpha * matched
 
         gap_proxy = None
         if self.monitor is not None:
@@ -347,15 +351,17 @@ class FEELTrainer:
             grads = tele.block(grads)
 
         # ---- fault application + resilience policies ------------------
-        planned = np.asarray(uploaded) > 0
-        surv = planned
-        n_dropped = n_retries = 0
-        if self._resilient:
-            surv, n_dropped, n_retries = self._upload_outcomes(
-                i, rf, planned, tele)
-            grads = self._inject_nan_uploads(rf, surv, grads, tele)
-            surv, n_bad = self._screen_nonfinite(i, rf, surv, grads, tele)
-            n_dropped += n_bad
+        with tele.span("round.uploads"):
+            planned = np.asarray(uploaded) > 0
+            surv = planned
+            n_dropped = n_retries = 0
+            if self._resilient:
+                surv, n_dropped, n_retries = self._upload_outcomes(
+                    i, rf, planned, tele)
+                grads = self._inject_nan_uploads(rf, surv, grads, tele)
+                surv, n_bad = self._screen_nonfinite(i, rf, surv, grads,
+                                                     tele)
+                n_dropped += n_bad
 
         g_norm_sq = None
         skipped_update = False
@@ -405,35 +411,41 @@ class FEELTrainer:
                 self.params = tele.block(optim.apply_updates(self.params,
                                                              updates))
 
-        sel = np.asarray(delta) > 0.5
-        mislabeled = (np.asarray(labels) != true)
-        frac_bad = (float(np.sum(sel & mislabeled)) / max(np.sum(sel), 1))
+        with tele.span("round.record"):
+            sel = np.asarray(delta) > 0.5
+            mislabeled = (np.asarray(labels) != true)
+            frac_bad = (float(np.sum(sel & mislabeled))
+                        / max(np.sum(sel), 1))
+            self._cum = self._cum + dec.net_cost
+            n_uploaded = int(np.sum(surv))
         acc = None
         if eval_now:
             with tele.stage("eval"):
                 acc = tele.block(self.model.accuracy(
                     self.params, self.data.test_images,
                     self.data.test_labels))
-        self._cum = self._cum + dec.net_cost
-        n_uploaded = int(np.sum(surv))
         reg = metrics_mod.get_default()
         wall_s = time.perf_counter() - t_round
         if tele.enabled or reg.enabled:
-            e_cmp, e_com = self._energy_terms(dec)
-            if tele.enabled:
-                self._record_round(tele, dec, sel, mislabeled,
-                                   surv.astype(np.int64), acc, wall_s,
-                                   e_cmp, e_com)
-            if reg.enabled:
-                self._record_metrics(reg, dec, e_cmp, e_com,
-                                     int(np.sum(sel)), n_uploaded, wall_s)
-            if tele.enabled and reg.enabled:
-                tele.emit(reg.snapshot_event(round=i))
+            # work done only because a sink or registry is on
+            with tele.span("telemetry"):
+                e_cmp, e_com = self._energy_terms(dec)
+                if tele.enabled:
+                    self._record_round(tele, dec, sel, mislabeled,
+                                       surv.astype(np.int64), acc, wall_s,
+                                       e_cmp, e_com)
+                if reg.enabled:
+                    self._record_metrics(reg, dec, e_cmp, e_com,
+                                         int(np.sum(sel)), n_uploaded,
+                                         wall_s)
+                if tele.enabled and reg.enabled:
+                    tele.emit(reg.snapshot_event(round=i))
         if self.monitor is not None:
             stage_s = None
             if tele.enabled:
-                stage_s = {e.stage: e.dur_s for e in tele.events[ev0:]
-                           if isinstance(e, obs.StageEvent)}
+                with tele.span("telemetry"):
+                    stage_s = {e.stage: e.dur_s for e in tele.events[ev0:]
+                               if isinstance(e, obs.StageEvent)}
             self.monitor.observe_round(
                 i, gap=gap_proxy, g_norm_sq=g_norm_sq, eta=cfg.lr,
                 delta_obj=float(dec.delta_obj), wall_s=wall_s,
@@ -458,7 +470,8 @@ class FEELTrainer:
                             n_retries=n_retries,
                             skipped_update=skipped_update,
                             fallbacks=dec.fallbacks,
-                            feasible=bool(dec.feasible))
+                            feasible=bool(dec.feasible),
+                            decision=dec)
 
     def _profile_once(self, name: str, stage: str, fn, args, tele,
                       round_i: int) -> None:

@@ -55,7 +55,7 @@ from .spans import (SpanNode, build_tree, iter_spans,  # noqa: F401
                     self_seconds_by_path)
 from .summary import load_trace, rows, summarize  # noqa: F401
 from .summary import emit as emit_summary  # noqa: F401
-from .trace import (NULL, NullTelemetry, Telemetry, annotate_fn,  # noqa: F401
+from .trace import (NULL, NullTelemetry, Telemetry,  # noqa: F401
                     get_default, resolve, set_default)
 
 __all__ = [
@@ -64,7 +64,7 @@ __all__ = [
     "RoundEvent", "MetricsEvent", "MonitorEvent", "ProfileEvent",
     "FaultEvent", "SpanEvent",
     "parse_record", "NullTelemetry", "Telemetry", "NULL",
-    "set_default", "get_default", "resolve", "annotate_fn",
+    "set_default", "get_default", "resolve",
     "NullRegistry", "Registry", "render_snapshot",
     "ConvergenceMonitor", "MonitorConfig", "Violation",
     "KernelProfile", "cost_of", "peak_flops", "profile_jitted",

@@ -16,7 +16,11 @@ Two sinks share one interface:
   as the legacy ``stage`` record and feeds ``feel_stage_seconds``.
   ``block`` calls ``jax.block_until_ready`` so device work is
   attributed to the stage that launched it rather than to whichever
-  later stage happens to synchronize.
+  later stage happens to synchronize.  Every span and stage also opens
+  a ``jax.profiler.TraceAnnotation`` under its own name, so a profile
+  taken while the sink records shows the span tree as host events on
+  the device trace's clock, around the launches and transfers each
+  span makes.
 
 Sink resolution: instrumented entry points take ``telemetry=None`` and
 call ``resolve`` — ``None`` means "use the process default" (set with
@@ -32,6 +36,8 @@ import json
 import time
 import warnings
 from typing import Any, Dict, IO, Optional
+
+import jax
 
 from . import events as ev
 from . import metrics as metrics_mod
@@ -56,7 +62,6 @@ class NullTelemetry:
     """Do-nothing sink; the interface contract for ``Telemetry``."""
 
     enabled: bool = False
-    annotate: bool = False
     profile: bool = False
 
     def stage(self, name: str):
@@ -97,11 +102,12 @@ NULL = NullTelemetry()
 
 class _Span:
     """Timed span context: allocates an id on entry, pushes itself on
-    the sink's span stack (so nested spans know their parent), and
-    emits one event on exit.  ``_TimedStage`` specializes the emitted
-    event kind; everything else is shared."""
+    the sink's span stack (so nested spans know their parent), holds a
+    profiler annotation of the same name open, and emits one event on
+    exit.  ``_TimedStage`` specializes the emitted event kind;
+    everything else is shared."""
 
-    __slots__ = ("_tele", "_name", "_attrs", "_t0", "span_id",
+    __slots__ = ("_tele", "_name", "_attrs", "_t0", "_ann", "span_id",
                  "parent_id")
 
     def __init__(self, tele: "Telemetry", name: str,
@@ -116,11 +122,16 @@ class _Span:
         stack = tele._span_stack
         self.parent_id = stack[-1] if stack else None
         stack.append(self.span_id)
+        self._ann = jax.profiler.TraceAnnotation(self._name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        # each annotation closes its own interval, so an out-of-order
+        # exit below still records this span's bounds
+        self._ann.__exit__(None, None, None)
         tele = self._tele
         stack = tele._span_stack
         # tolerate out-of-order exits (crash paths): pop down to self
@@ -165,11 +176,6 @@ class Telemetry(NullTelemetry):
     ----------
     path:
         JSONL output file; ``None`` keeps events in memory only.
-    annotate:
-        ask ``FEELTrainer`` to wrap its jitted functions in
-        ``jax.profiler`` trace annotations (visible in TensorBoard /
-        Perfetto profiles; off by default — it renames traced
-        computations, which can perturb compilation caching).
     profile:
         ask instrumented trainers to record one ``ProfileEvent``
         (HLO FLOPs / bytes, ``repro.obs.profile``) per jitted function
@@ -185,10 +191,8 @@ class Telemetry(NullTelemetry):
 
     enabled = True
 
-    def __init__(self, path: Optional[str] = None, annotate: bool = False,
-                 profile: bool = False,
+    def __init__(self, path: Optional[str] = None, profile: bool = False,
                  meta: Optional[Dict[str, Any]] = None):
-        self.annotate = annotate
         self.profile = profile
         self.created_s = time.perf_counter()
         self.current_round: Optional[int] = None
@@ -216,8 +220,6 @@ class Telemetry(NullTelemetry):
         return self._span_seq
 
     def block(self, x):
-        import jax
-
         return jax.block_until_ready(x)
 
     def begin_round(self, i: int) -> None:
@@ -299,13 +301,3 @@ def resolve(telemetry: Optional[NullTelemetry]) -> NullTelemetry:
     """``None`` -> the process default; anything else passes through."""
     return _default if telemetry is None else telemetry
 
-
-def annotate_fn(fn, name: str):
-    """Wrap ``fn`` in a ``jax.profiler`` trace annotation when the
-    running jax exposes one; otherwise return ``fn`` unchanged."""
-    try:
-        import jax.profiler
-
-        return jax.profiler.annotate_function(fn, name=name)
-    except Exception:  # pragma: no cover - profiler API unavailable
-        return fn

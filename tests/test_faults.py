@@ -289,3 +289,19 @@ def test_resolve_policy_runs():
     assert any("resolve_survivors" in m.fallbacks for m in ms)
     for leaf in jax.tree.leaves(tr.params):
         assert bool(np.isfinite(np.asarray(leaf)).all())
+
+
+def test_resolved_decision_is_the_one_reported():
+    """After a survivor re-solve, ``RoundMetrics.decision`` is the
+    re-solved decision: RBs only for the devices that uploaded."""
+    spec = FaultSpec(seed=1, dropout_prob=0.5)
+    tr = _build_trainer(faults=spec,
+                        res=ResilienceConfig(dropout_policy="resolve"))
+    ms = tr.run(3)
+    resolved = [m for m in ms if "resolve_survivors" in m.fallbacks]
+    assert resolved
+    for m in ms:
+        assert m.decision.fallbacks == m.fallbacks
+        assert m.decision.net_cost == m.net_cost
+    for m in resolved:
+        assert int(m.decision.rho.sum()) == m.n_uploaded

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.core import convergence, default_system
+from repro.core import joint
 from repro.data import SyntheticImages, non_iid_split
 from repro.fed import FEELConfig, FEELTrainer, per_sample_sigma
 from repro.fed.server import aggregate_gradients
@@ -163,3 +164,25 @@ def test_lemma2_bound_on_quadratic():
         gaps.append(L(w - eta * ghat) - L(w_star))
     se = float(np.std(gaps) / np.sqrt(len(gaps)))
     assert np.mean(gaps) <= float(bound) + 3 * se
+
+
+def test_round_metrics_carry_the_applied_decision(monkeypatch):
+    """In clean rounds ``RoundMetrics.decision`` is, array for array,
+    what ``joint.proposed_scheme`` returned."""
+    from tests.test_obs import _assert_decisions_equal, _tiny_trainer
+
+    seen = []
+    scheme = joint.proposed_scheme
+
+    def recorded(*args, **kw):
+        seen.append(scheme(*args, **kw))
+        return seen[-1]
+
+    monkeypatch.setattr(joint, "proposed_scheme", recorded)
+    ms = _tiny_trainer().run(3)
+    assert len(seen) == 3
+    for m, dec in zip(ms, seen):
+        assert m.fallbacks == () and not m.skipped_update
+        _assert_decisions_equal(m.decision, dec)
+        assert m.net_cost == dec.net_cost
+        assert m.n_selected == int(np.sum(dec.delta > 0.5))

@@ -1,15 +1,19 @@
 """Tests for the repro.obs telemetry subsystem: JSONL round-trip,
 no-op default sink, and the instrumented FEELTrainer round."""
+import collections
+import glob
 import json
+import os
 import time
 import types
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import default_system
+from repro.core import default_system, selection
 from repro.data import SyntheticImages, non_iid_split
 from repro.fed import FEELConfig, FEELTrainer
 from repro.models import cnn
@@ -280,3 +284,116 @@ def test_full_observability_is_bit_for_bit_identical(tmp_path):
     assert {"selection.gp", "selection.recover"} <= span_names
     assert reg.counter("feel_rounds_total").value() == 2.0
     assert inst.monitor.summary()["rounds"] == 2
+
+
+def _assert_decisions_equal(a, b):
+    for f in ("rho", "p", "delta", "unmatched"):
+        assert np.array_equal(getattr(a, f), getattr(b, f)), f
+    for f in ("net_cost", "delta_obj", "objective", "feasible", "swaps",
+              "fallbacks"):
+        assert getattr(a, f) == getattr(b, f), f
+
+
+def test_null_sink_builds_no_annotation_and_is_bit_identical(monkeypatch):
+    """A round on the NULL sink opens no profiler annotation, and it
+    computes exactly what a recording round computes."""
+    rec = _tiny_trainer(telemetry=obs.Telemetry())
+    ms_rec = rec.run(2)
+
+    class Refused:
+        def __init__(self, *args, **kw):
+            raise AssertionError("the NULL sink built a TraceAnnotation")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refused)
+    with pytest.raises(AssertionError):
+        obs.Telemetry().span("x").__enter__()
+    null = _tiny_trainer(telemetry=obs.NULL)
+    assert null.obs is obs.NULL
+    ms_null = null.run(2)
+
+    for a, b in zip(jax.tree.leaves(rec.params),
+                    jax.tree.leaves(null.params)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    for ma, mb in zip(ms_rec, ms_null):
+        assert ma.net_cost == mb.net_cost
+        assert ma.n_selected == mb.n_selected
+        assert ma.n_uploaded == mb.n_uploaded
+        assert ma.test_acc == mb.test_acc
+        _assert_decisions_equal(ma.decision, mb.decision)
+
+
+# ------------------------------------------- spans on the profiler clock
+
+def _profiled(fn, log_dir):
+    """Run ``fn`` under the profiler; return the host events of the
+    trace as ``name -> [(start_ns, end_ns), ...]``."""
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(log_dir), profiler_options=options):
+        fn()
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    events = collections.defaultdict(list)
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events[e.name].append((int(e.start_ns), int(e.end_ns)))
+    return events
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_round_spans_are_profiler_annotations(tmp_path):
+    tele = obs.Telemetry()
+    trainer = _tiny_trainer(telemetry=tele)
+    trainer.run_round(0)  # compiles outside the profile
+    events = _profiled(lambda: trainer.run_round(1), tmp_path)
+
+    (rnd,) = events["round"]
+    for name in ("round.draws", "data", "sigma", "matching.prep",
+                 "matching", "power", "selection", "objective",
+                 "joint.finish", "round.uploads", "local_grads",
+                 "aggregate", "round.record", "telemetry"):
+        assert events[name], f"no {name!r} annotation in the trace"
+        assert all(_inside(e, rnd) for e in events[name]), name
+    # two openings each of round.draws and round.uploads; the telemetry
+    # of the round and of the selection solve
+    assert len(events["round.draws"]) == 2
+    assert len(events["round.uploads"]) == 2
+    assert len(events["telemetry"]) == 2
+    # the annotations carry the recorded spans' own nesting
+    (sel,) = events["selection"]
+    assert any(_inside(e, sel) for e in events["telemetry"])
+    assert all(_inside(e, sel) for e in events["selection.gp"])
+
+
+def test_selection_count_sync_sits_in_telemetry_span(tmp_path):
+    sys_ = default_system(K=4, N=3, Q=2, D_hat=8)
+    sigma = jax.random.uniform(jax.random.PRNGKey(0), (4, 8))
+    mask = jnp.ones_like(sigma)
+    tele = obs.Telemetry()
+
+    def solve():
+        with tele.stage("selection"):
+            tele.block(selection.solve_selection(sys_, sigma, mask, steps=20,
+                                                 telemetry=tele))
+
+    solve()  # compiles outside the profile
+    events = _profiled(solve, tmp_path)
+
+    (sel,) = events["selection"]
+    (tel,) = events["telemetry"]
+    assert _inside(tel, sel)
+    # every transfer to the host in the solve is the count's, and it
+    # falls inside the telemetry span
+    syncs = [e for e in events["np.asarray(jax.Array)"] if _inside(e, sel)]
+    assert len(syncs) == 1 and _inside(syncs[0], tel)
+    # the recorded tree has the same shape
+    spans = {e.name: e for e in tele.events if isinstance(e, obs.SpanEvent)}
+    stages = [e for e in tele.events if isinstance(e, obs.StageEvent)]
+    assert spans["telemetry"].parent_id == stages[-1].span_id
