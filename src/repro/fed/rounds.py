@@ -166,6 +166,9 @@ class FEELTrainer:
             else ResilienceConfig()
         self._strikes = np.zeros(sys.K, np.int64)
         self._quarantined_until = np.zeros(sys.K, np.int64)
+        # devices whose eq. (19) weight is nonzero when they upload
+        self._ipw_live = (np.asarray(sys.eps) > 0) & \
+            (np.asarray(sys.D_hat) > 0)
         self._start_round = 0
         self._cum = 0.0
         self._profiled: set = set()
@@ -225,9 +228,37 @@ class FEELTrainer:
 
             return jax.vmap(one_device)(images, labels, delta)
 
+        def server_step(sys, params, opt_state, grads, alpha, renormalize):
+            """eq. (19) and the optimizer step (eq. 20) as one program;
+            returns ``(params, opt_state, g_hat)``.
+
+            ``renormalize`` (static) is the survivor path: the lost
+            uploads are zeroed before the weighted sum (their IPW weight
+            is 0, but 0 * NaN would still poison it) and the sum is
+            divided by the survivors' realized IPW mass.  The functions
+            are looked up through their modules when the step is traced.
+            """
+            metrics_mod.get_default().counter(
+                "feel_server_step_traces_total",
+                "traces of the fused server step").inc()
+            if renormalize:
+                live = alpha > 0
+
+                def scrub(leaf):
+                    shape = (sys.K,) + (1,) * (leaf.ndim - 1)
+                    return jnp.where(live.reshape(shape), leaf, 0.0)
+
+                grads = jax.tree.map(scrub, grads)
+            g_hat = server_mod.aggregate_gradients(sys, grads, alpha,
+                                                   renormalize=renormalize)
+            updates, opt_state = self.opt.update(g_hat, opt_state, params)
+            return optim.apply_updates(params, updates), opt_state, g_hat
+
         self._sigma_all = sigma_all
         self._local_grads = local_grads
         self._local_deltas = local_deltas
+        self._server_step = jax.jit(server_step,
+                                    static_argnames=("renormalize",))
 
     # ------------------------------------------------------------------
     def _gather_round_batches(self):
@@ -366,30 +397,20 @@ class FEELTrainer:
         g_norm_sq = None
         skipped_update = False
         with tele.stage("aggregate"):
-            if self._resilient and not np.array_equal(surv, planned):
-                surv_j = jnp.asarray(surv, jnp.float32)
+            # IPW-consistent reweighting over the survivor set when it
+            # differs from the planned one; else the clean eq. (19)
+            renormalize = bool(self._resilient
+                               and not np.array_equal(surv, planned))
+            alpha_agg = uploaded
+            if renormalize:
+                alpha_agg = jnp.asarray(surv, jnp.float32)
                 if self._res.dropout_policy == "resolve" and surv.any():
-                    dec = self._resolve_for_survivors(state, surv_j, dec,
-                                                      tele)
-                # zero the lost uploads before the weighted sum: their
-                # IPW weight is 0, but 0 * NaN would still poison it
-                surv_b = jnp.asarray(surv)
-
-                def scrub(leaf):
-                    shape = (sys.K,) + (1,) * (leaf.ndim - 1)
-                    return jnp.where(surv_b.reshape(shape), leaf, 0.0)
-
-                grads = jax.tree.map(scrub, grads)
-                # IPW-consistent reweighting over the survivor set
-                g_hat = server_mod.aggregate_gradients(sys, grads, surv_j,
-                                                       renormalize=True)
-                mass = server_mod.ipw_mass(sys, surv_j)
-            else:
-                # clean round: the exact pre-fault-tolerance aggregation
-                g_hat = server_mod.aggregate_gradients(sys, grads,
-                                                       uploaded)
-                mass = server_mod.ipw_mass(sys, uploaded)
-            if mass <= 0.0:
+                    dec = self._resolve_for_survivors(state, alpha_agg,
+                                                      dec, tele)
+            # every IPW weight is 0 or at least |D̂_k|, so the realized
+            # mass is positive exactly when an upload of a live device
+            # survived: decided on the host, with no sync
+            if not np.any(surv & self._ipw_live):
                 # every upload was lost (or none was scheduled): applying
                 # the zero/NaN step would still move Adam's state, so the
                 # update is skipped and recorded instead
@@ -403,13 +424,13 @@ class FEELTrainer:
                                  "rounds whose optimizer update was "
                                  "skipped (no usable upload)").inc()
             else:
+                params, self.opt_state, g_hat = self._server_step(
+                    sys, self.params, self.opt_state, grads, alpha_agg,
+                    renormalize=renormalize)
                 if self.monitor is not None:
                     g_norm_sq = float(sum(jnp.vdot(x, x)
                                           for x in jax.tree.leaves(g_hat)))
-                updates, self.opt_state = self.opt.update(
-                    g_hat, self.opt_state, self.params)
-                self.params = tele.block(optim.apply_updates(self.params,
-                                                             updates))
+                self.params = tele.block(params)
 
         with tele.span("round.record"):
             sel = np.asarray(delta) > 0.5

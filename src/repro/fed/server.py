@@ -16,8 +16,14 @@ Robustness extensions (docs/robustness.md):
   renormalizing keeps g_hat a convex combination of the surviving local
   gradients, so its direction stays consistent with the survivor set.
   With no survivors the result is an all-zeros tree — callers should
-  check ``ipw_mass`` first and skip the optimizer update entirely
-  (``FEELTrainer`` does).
+  skip the optimizer update entirely when the realized mass is zero.
+
+``FEELTrainer`` runs eq. (19) and the optimizer step as one compiled
+program (``FEELTrainer._server_step``), and makes the skip decision
+before it, on the host: every weight is 0 or at least ``|D̂_k|``, so
+the mass is positive exactly when an upload of a device with
+``eps_k > 0`` and ``|D̂_k| > 0`` survived.  ``ipw_mass`` computes the
+same answer on the device.
 """
 from __future__ import annotations
 

@@ -305,3 +305,37 @@ def test_resolved_decision_is_the_one_reported():
         assert m.decision.net_cost == m.net_cost
     for m in resolved:
         assert int(m.decision.rho.sum()) == m.n_uploaded
+
+
+def _server_step_traces(reg):
+    return reg.counter("feel_server_step_traces_total").value()
+
+
+def test_zero_ipw_mass_skips_update_without_the_server_step():
+    """Survivors with zero IPW mass: the update is skipped on the host,
+    parameters and optimizer state (Adam's count too) stay as they were,
+    and the fused server step is never traced."""
+    reg = obs.Registry()
+    obs.metrics.set_default(reg)
+    tr = _build_trainer(faults=FaultSpec(seed=0, dropout_prob=1.0),
+                        res=ResilienceConfig())
+    before = [np.asarray(x).copy()
+              for x in jax.tree.leaves((tr.params, tr.opt_state))]
+    m = tr.run_round(0)
+    assert m.skipped_update and m.n_uploaded == 0
+    after = jax.tree.leaves((tr.params, tr.opt_state))
+    assert all(np.array_equal(a, b) for a, b in zip(before, after))
+    assert int(tr.opt_state.count) == 0
+    assert _server_step_traces(reg) == 0
+    assert reg.counter("feel_rounds_skipped_total").value() == 1
+
+
+def test_server_step_traces_once_in_clean_rounds():
+    """Five clean rounds trace the fused server step once: a higher
+    count would be a retrace, so a compile, every round."""
+    reg = obs.Registry()
+    obs.metrics.set_default(reg)
+    tr = _build_trainer()
+    ms = tr.run(5)
+    assert not all(m.skipped_update for m in ms)
+    assert _server_step_traces(reg) == 1

@@ -186,3 +186,58 @@ def test_round_metrics_carry_the_applied_decision(monkeypatch):
         _assert_decisions_equal(m.decision, dec)
         assert m.net_cost == dec.net_cost
         assert m.n_selected == int(np.sum(dec.delta > 0.5))
+
+
+@pytest.mark.parametrize("path", ["clean", "renormalized"])
+@pytest.mark.parametrize("optimizer", ["adam", "sgd", "momentum",
+                                       "adafactor"])
+def test_server_step_matches_eager_sequence(optimizer, path):
+    """Over three rounds the fused server step gives the parameters and
+    optimizer state of the eager aggregate_gradients -> opt.update ->
+    apply_updates sequence; on the survivor path one upload, lost and
+    NaN, is scrubbed before the renormalized sum."""
+    from repro import optim
+    from tests.test_obs import _tiny_trainer
+
+    tr = _tiny_trainer(optimizer=optimizer)
+    sys_, K = tr.sys, tr.sys.K
+    renormalize = path == "renormalized"
+    fused = (tr.params, tr.opt_state)
+    eager = fused
+    for r in range(3):
+        leaves, treedef = jax.tree.flatten(tr.params)
+        keys = jax.random.split(jax.random.PRNGKey(r), len(leaves))
+        grads = treedef.unflatten([
+            jax.random.normal(k, (K,) + x.shape, x.dtype)
+            for k, x in zip(keys, leaves)])
+        alpha = jnp.ones((K,), jnp.float32)
+        if renormalize:
+            alpha = alpha.at[r % K].set(0.0)
+            grads = jax.tree.map(lambda g: g.at[r % K].set(jnp.nan), grads)
+        p, s, _ = tr._server_step(sys_, *fused, grads, alpha,
+                                  renormalize=renormalize)
+        fused = (p, s)
+
+        kept = grads
+        if renormalize:
+            kept = jax.tree.map(
+                lambda g: jnp.where(
+                    (alpha > 0).reshape((K,) + (1,) * (g.ndim - 1)), g, 0.0),
+                grads)
+        g_hat = aggregate_gradients(sys_, kept, alpha,
+                                    renormalize=renormalize)
+        updates, s = tr.opt.update(g_hat, eager[1], eager[0])
+        eager = (optim.apply_updates(eager[0], updates), s)
+
+    assert jax.tree.structure(fused) == jax.tree.structure(eager)
+    for a, b in zip(jax.tree.leaves(fused), jax.tree.leaves(eager)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype
+        if np.issubdtype(a.dtype, np.integer):   # Adam's count
+            np.testing.assert_array_equal(a, b)
+        else:
+            # float32: rtol 1e-5, and near zero the rounding of the
+            # leaf's largest terms (a sum's order may differ in one program)
+            assert np.isfinite(a).all()
+            np.testing.assert_allclose(a, b, rtol=1e-5,
+                                       atol=1e-6 * np.abs(b).max())

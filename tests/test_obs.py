@@ -175,13 +175,14 @@ def test_telemetry_close_is_idempotent(tmp_path):
 
 # ------------------------------------------------------- trainer round
 
-def _tiny_trainer(telemetry=None, scheme="proposed"):
+def _tiny_trainer(telemetry=None, scheme="proposed", optimizer="adam"):
     train = SyntheticImages.make(200, side=8, seed=0)
     test = SyntheticImages.make(50, side=8, seed=1)
     data = non_iid_split(train, test, K=4, per_device=20,
                          mislabel_prop=0.2, seed=0)
     sys_ = default_system(K=4, N=3, Q=2, D_hat=8)
-    cfg = FEELConfig(scheme=scheme, d_hat=8, gp_steps=20, eval_every=1)
+    cfg = FEELConfig(scheme=scheme, d_hat=8, gp_steps=20, eval_every=1,
+                     optimizer=optimizer)
     cc = cnn.CNNConfig(side=8)
     params = cnn.init(jax.random.PRNGKey(0), cc)
     model = types.SimpleNamespace(features=cnn.features, apply=cnn.apply,
