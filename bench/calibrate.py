@@ -60,7 +60,7 @@ def readings(cell, seed: int, faulted: bool) -> dict:
     data, params0, observed = _program(cell, sub)
     runs = {"program": observed}
     if faulted:
-        ctl = reference.Reference(cell.cfg, cell.traffic, sub,
+        ctl = reference.Reference(cell.cfg, cell.traffic, sub, cell.model,
                                   dtype=jnp.bfloat16, precision=None)
         runs["control"] = as_observed(ctl.run(data, params0,
                                               bench_run.SETUP_ROUNDS))
@@ -69,7 +69,8 @@ def readings(cell, seed: int, faulted: bool) -> dict:
                 runs[name] = _program(cell, sub)[2]
     out = {"seed": seed}
     for kind, got in runs.items():
-        ref = reference.Reference(cell.cfg, cell.traffic, sub).run(
+        ref = reference.Reference(
+            cell.cfg, cell.traffic, sub, cell.model).run(
             data, params0, bench_run.SETUP_ROUNDS, selections=got.delta)
         out[kind] = correct.compare(got, ref)
         out[kind + "_rounds"] = correct.per_round(got, ref)

@@ -1,12 +1,14 @@
 """Plain reference of the FEEL round, written from the paper.
 
 It imports nothing of the program. Given a configuration, a traffic
-mix and the run's sub-seeds, it follows the first rounds of training
-in straightforward ``jax.numpy`` and NumPy:
+mix, the run's sub-seeds and the model module's part of the reference
+(``bench/models/<model>.py``: forward pass, sigma, weighted loss), it
+follows the first rounds of training in straightforward ``jax.numpy``
+and NumPy:
 
 1. each device draws |D̂_k| of its samples (the trainer's NumPy stream,
-   seeded by the rounds sub-seed) and scores them with sigma, the
-   squared gradient norm of the output layer, ``|p - y|^2 (|h|^2 + 1)``;
+   seeded by the rounds sub-seed) and scores them with the model's
+   sigma;
 2. channel gains h ~ Exp * MEAN_GAIN and availability alpha ~ Bern(eps)
    come from the trainer's JAX key stream;
 3. the decision: Alg. 2 swap matching (greedy best-gain start, pairwise
@@ -15,10 +17,10 @@ in straightforward ``jax.numpy`` and NumPy:
    powers, then those powers; Alg. 4 gradient projection and Alg. 5
    thresholding for the selection;
 4. eq. (4) local gradients and the eq. (19) inverse-propensity sum, as
-   one gradient of the weighted loss (eq. 19 is linear in the uploads),
-   over the selection that ``selections`` names where it is given (the
-   program's, as a served model's reference is fed the served tokens),
-   else over its own;
+   one gradient of the model's weighted loss (eq. 19 is linear in the
+   uploads), over the selection that ``selections`` names where it is
+   given (the program's, as a served model's reference is fed the
+   served tokens), else over its own;
 5. an Adam step (skipped when no upload survived).
 
 ``dtype``/``precision`` choose the arithmetic: float32 at ``HIGHEST``
@@ -59,48 +61,6 @@ class Trajectory:
     rounds: List[RoundOut]
     params0: dict
     params: dict           # after the last round
-
-
-# ---------------------------------------------------------------- model
-
-def forward(params, x, precision):
-    """(penultimate h, logits) of the CNN for images x: (B, S, S)."""
-    def conv(x, w, b):
-        y = jax.lax.conv_general_dilated(
-            x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            precision=precision)
-        return y + b
-
-    def pool(x):  # 2x2 max-pooling, stride 2
-        b, hh, ww, c = x.shape
-        return x.reshape(b, hh // 2, 2, ww // 2, 2, c).max(axis=(2, 4))
-
-    x = x[..., None]
-    x = pool(jax.nn.relu(conv(x, params["conv1"]["w"], params["conv1"]["b"])))
-    x = pool(jax.nn.relu(conv(x, params["conv2"]["w"], params["conv2"]["b"])))
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(jnp.dot(x, params["fc1"]["w"], precision=precision)
-                    + params["fc1"]["b"])
-    h = jax.nn.relu(jnp.dot(x, params["fc2"]["w"], precision=precision)
-                    + params["fc2"]["b"])
-    return h, jnp.dot(h, params["out"]["w"], precision=precision) \
-        + params["out"]["b"]
-
-
-def _sigma(params, x, y, precision):
-    h, logits = forward(params, x, precision)
-    r = jax.nn.softmax(logits) - jax.nn.one_hot(y, logits.shape[-1],
-                                                dtype=logits.dtype)
-    return jnp.sum(r * r, axis=-1) * (jnp.sum(h * h, axis=-1) + 1)
-
-
-def _weighted_loss(params, x, y, w, precision):
-    """sum_k w_k * (selected-mean CE of device k); x: (k, J, S, S),
-    w: (k, J) = device weight times its selection over its count."""
-    _, logits = forward(params, x.reshape((-1,) + x.shape[2:]), precision)
-    logp = jax.nn.log_softmax(logits)
-    ce = -jnp.take_along_axis(logp, y.reshape(-1, 1), axis=1)[:, 0]
-    return jnp.sum(w.reshape(-1) * ce)
 
 
 # -------------------------------------------------------------- decision
@@ -243,9 +203,10 @@ def select(sigma, A, q, lam, steps, step0):
 # ------------------------------------------------------------------ round
 
 class Reference:
-    """The reference (or, in bfloat16, the control) for one cell."""
+    """The reference (or, in bfloat16, the control) for one cell;
+    ``model`` is the cell's model module."""
 
-    def __init__(self, cfg: dict, traffic: dict, sub: dict,
+    def __init__(self, cfg: dict, traffic: dict, sub: dict, model,
                  dtype=jnp.float32, precision=HIGHEST):
         if cfg["optimizer"] != "adam" or traffic["scheme"] != "proposed":
             raise ValueError("the reference follows Adam under the "
@@ -263,13 +224,23 @@ class Reference:
         self.d = np.full(K, d)
         self.A = d * d / self.eps + d * (d * K - d)
         self.gamma = 2.0 ** (cfg["L_bits"] / (cfg["B_hz"] * cfg["T_s"])) - 1
-        self._sigma = jax.jit(lambda p, x, y: _sigma(p, x, y, precision))
+        self._sigma = jax.jit(
+            lambda p, x, y: model.sigma(p, x, y, precision))
+        # x: (k, J, ...) and y, w: (k, J), flattened to one batch
         self._grad = jax.jit(jax.grad(
-            lambda p, x, y, w: _weighted_loss(p, x, y, w, precision)))
+            lambda p, x, y, w: model.weighted_loss(
+                p, x.reshape((-1,) + x.shape[2:]), y.reshape(-1),
+                w.reshape(-1), precision)))
         self._select = jax.jit(select, static_argnames=("steps", "step0"))
 
     def _cast(self, tree):
         return jax.tree.map(lambda a: jnp.asarray(a, self.dtype), tree)
+
+    def _inputs(self, x):
+        """Floating inputs in the reference's dtype; ids as they are."""
+        if np.issubdtype(x.dtype, np.floating):
+            return jnp.asarray(x, self.dtype)
+        return jnp.asarray(x)
 
     def _blocks(self, K):
         return [(a, min(a + BLOCK, K)) for a in range(0, K, BLOCK)]
@@ -287,8 +258,9 @@ class Reference:
 
     def run(self, data, params0, rounds: int = 3,
             selections=None) -> Trajectory:
-        """``selections``: per round, the (K, J) selection whose samples
-        the gradient is taken over; ``None`` takes the reference's own."""
+        """``data``: an ``inputs.Samples``; ``selections``: per round, the
+        (K, J) selection whose samples the gradient is taken over;
+        ``None`` takes the reference's own."""
         cfg = self.cfg
         K, J = cfg["K"], cfg["d_hat"]
         rng = np.random.default_rng(self.sub["rounds"])
@@ -303,10 +275,10 @@ class Reference:
         out = []
         for i in range(rounds):
             idx = [rng.choice(len(x), size=min(J, len(x)), replace=False)
-                   for x in data.images]
-            x = np.stack([data.images[k][idx[k]] for k in range(K)])
+                   for x in data.x]
+            x = np.stack([data.x[k][idx[k]] for k in range(K)])
             y = np.stack([data.labels[k][idx[k]] for k in range(K)])
-            x_d = jnp.asarray(x, self.dtype)
+            x_d = self._inputs(x)
             y_d = jnp.asarray(y)
             sigma = jnp.concatenate([
                 self._sigma(params, x_d[a:b].reshape((-1,) + x.shape[2:]),

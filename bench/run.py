@@ -5,10 +5,13 @@
 One process, one cell, one run. The cell (``BENCHMARK.json``) names a
 configuration (``bench/configs/<config>.json``: the model's widths and
 the deployment) and a traffic mix (``bench/traffic/<mix>.json``: the
-scheme and the devices' availability). From ``--seed`` the run makes the
-data and the weights, builds one ``FEELTrainer`` and drives it through
-its first rounds (set-up: compilation and warm-up), then measures a
-closed loop of ``run_round`` calls, each ended by
+scheme and the devices' availability). The configuration's ``"model"``
+names its model module, ``bench/models/<model>.py`` (``cnn`` where the
+key is absent), which makes the data, the weights, the program's model
+object, the FLOPs and the reference's model part. From ``--seed`` the
+run makes the data and the weights, builds one ``FEELTrainer`` and
+drives it through its first rounds (set-up: compilation and warm-up),
+then measures a closed loop of ``run_round`` calls, each ended by
 ``block_until_ready(trainer.params)``, for ``--seconds``. With
 ``--trace 1`` the program's telemetry records stage spans in the window
 (each stage then ends in a ``block_until_ready`` of its own) and a few
@@ -20,10 +23,11 @@ programs there.
 
 After the window the first rounds are checked against the plain
 reference (``reference.py``, ``correct.py``). Each metric is computed
-by its reader, ``bench/metrics/<metric>.py``. The last line on stdout
-is the result as JSON; the numbers compared, beside their limits, are
-the last lines on stderr. Without a TPU the run exits non-zero and
-prints no result.
+by its reader, ``bench/metrics/<metric>.py``, from a ``Context``: the
+window's walls, each traced round's span tree, and the profile's device
+time by op and by program. The last line on stdout is the result as
+JSON; the numbers compared, beside their limits, are the last lines on
+stderr. Without a TPU the run exits non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -32,7 +36,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
@@ -41,7 +44,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+from typing import Any, Dict, List, Optional  # noqa: E402
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -53,7 +56,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 import correct  # noqa: E402
-import flops  # noqa: E402
 import inputs  # noqa: E402
 import peaks  # noqa: E402
 import reference  # noqa: E402
@@ -66,6 +68,8 @@ SETUP_ROUNDS = 3
 PROFILE_S = 1.0
 PROFILE_ROUNDS = 2
 STEP_NAME = "feel_round"
+#: the model module of a configuration that names none
+DEFAULT_MODEL = "cnn"
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/core/compile/jaxpr_trace_duration")
 
@@ -77,6 +81,7 @@ class Cell:
     cfg: dict
     traffic: dict
     metrics: List[dict]      # end_to_end with --trace 0, else per_layer
+    model: Any               # the configuration's model module
 
 
 def load_cell(workload: str, trace: bool) -> Cell:
@@ -95,17 +100,36 @@ def load_cell(workload: str, trace: bool) -> Cell:
     kind = "per_layer" if trace else "end_to_end"
     metrics = [m for m in spec[kind]
                if workload in m.get("workloads", [workload])]
-    return Cell(workload, w["chips"], cfg, traffic, metrics)
+    model = model_module(cfg.get("model", DEFAULT_MODEL))
+    return Cell(workload, w["chips"], cfg, traffic, metrics, model)
+
+
+def _load(folder: str, name: str):
+    """The module ``bench/<folder>/<name>.py``, loaded by its path."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{folder}_" + name.replace(".", "_").replace("-", "_"),
+        os.path.join(BENCH, folder, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(name: str):
     """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = os.path.join(BENCH, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load("metrics", name).read
+
+
+def model_module(name: str):
+    """``bench/models/<name>.py``; an unknown name exits with the list
+    of known model modules."""
+    folder = os.path.join(BENCH, "models")
+    path = os.path.join(folder, name + ".py")
+    if not os.path.isfile(path):
+        known = sorted(f[:-3] for f in os.listdir(folder)
+                       if f.endswith(".py"))
+        raise SystemExit(f"unknown model {name!r}: no bench/models/"
+                         f"{name}.py; known: {known}")
+    return _load("models", name)
 
 
 @dataclasses.dataclass
@@ -115,11 +139,17 @@ class Context:
     setup_s: float
     walls: List[float]                 # each window round's seconds
     window_s: float
+    cfg: dict = dataclasses.field(default_factory=dict)
+    model: Any = None                  # the configuration's model module
     #: per traced window round: {"round": dur_s, <stage>: dur_s, ...}
     spans: List[Dict[str, float]] = dataclasses.field(default_factory=list)
+    #: per traced window round, its ``round`` span tree with attributes
+    trees: List[Any] = dataclasses.field(default_factory=list)
     profile: Optional[dict] = None     # trace_reduce.reduce() output
     profile_flops: float = 0.0         # model FLOPs of the profiled rounds
+    profile_selected: List[int] = dataclasses.field(default_factory=list)
     peak_flops: float = 0.0
+    peak_bytes_per_s: float = 0.0
 
     def stage_ms(self, stage: str) -> Optional[float]:
         """Mean per round of the ``round/<stage>`` span, inclusive."""
@@ -127,6 +157,39 @@ class Context:
             return None
         return 1e3 * sum(s.get(stage, 0.0) for s in self.spans) \
             / len(self.spans)
+
+    def span_nodes(self, name: str) -> list:
+        """Every span named ``name``, at any depth, of the traced rounds
+        (a ``repro.obs.spans.SpanNode``: ``dur_s``, ``attrs``, ...)."""
+        return [n for t in self.trees for n in t.walk() if n.name == name]
+
+    def span_ms(self, name: str) -> Optional[float]:
+        """Mean per traced round of the spans named ``name`` at any
+        depth, inclusive, summed within a round (one inside another
+        counted once); ``None`` where no round has one."""
+        def outermost(node):
+            if node.name == name:
+                return node.dur_s
+            return sum(outermost(c) for c in node.children)
+
+        if not self.span_nodes(name):
+            return None
+        return 1e3 * sum(map(outermost, self.trees)) / len(self.trees)
+
+    def program_ms(self, name: str) -> Optional[float]:
+        """Device ms per profiled round of the XLA programs named
+        ``name`` (``jit_<function>``); ``None`` where none ran."""
+        return self._per_round("programs_s", name)
+
+    def op_ms(self, name: str) -> Optional[float]:
+        """Device ms per profiled round of the operation ``name`` as the
+        trace names it (``%fusion.6``); ``None`` where it did not run."""
+        return self._per_round("ops_s", name)
+
+    def _per_round(self, table: str, name: str) -> Optional[float]:
+        if self.profile is None or name not in self.profile[table]:
+            return None
+        return 1e3 * self.profile[table][name] / self.profile["steps"]
 
 
 def log(msg: str) -> None:
@@ -166,21 +229,14 @@ class CompileCounter:
 
 
 def build(cell: Cell, sub: dict, tele):
-    """``(placement, params0, trainer)`` of one run."""
+    """``(samples, params0, trainer)`` of one run."""
     from repro.core.types import SystemParams
-    from repro.data.federated import FederatedDataset
     from repro.fed import FEELConfig, FEELTrainer
-    from repro.models import cnn
 
-    cfg, traffic = cell.cfg, cell.traffic
-    K, side = cfg["K"], cfg["side"]
-    data = inputs.placement(cfg, sub["data"])
-    fed = FederatedDataset(
-        device_images=data.images, device_labels=data.labels,
-        device_true=data.true,
-        test_images=np.zeros((0, side, side), np.float32),
-        test_labels=np.zeros((0,), np.int32),
-        num_classes=cfg["num_classes"])
+    cfg, traffic, model = cell.cfg, cell.traffic, cell.model
+    K = cfg["K"]
+    data = model.samples(cfg, sub["data"])
+    fed = model.dataset(cfg, data)
     k1 = np.arange(1, K + 1)
     odd = k1 % 2 == 1
 
@@ -198,54 +254,30 @@ def build(cell: Cell, sub: dict, tele):
         eps=per_device(traffic["eps_odd"], traffic["eps_even"]),
         D_hat=jnp.full((K,), float(cfg["d_hat"])),
         lam=jnp.asarray(cfg["lam"]))
-    params0 = inputs.make_params(cfg, sub["weights"])
+    params0 = model.init_params(cfg, sub["weights"])
     fcfg = FEELConfig(scheme=traffic["scheme"], d_hat=cfg["d_hat"],
                       optimizer=cfg["optimizer"], lr=cfg["lr"],
                       gp_steps=cfg["gp_steps"], gp_step0=cfg["gp_step0"],
                       seed=sub["rounds"])
-    trainer = FEELTrainer(sys_, fed, cnn, params0, fcfg, telemetry=tele)
+    trainer = FEELTrainer(sys_, fed, model.program(cfg), params0, fcfg,
+                          telemetry=tele)
     return data, params0, trainer
-
-
-@contextlib.contextmanager
-def observe():
-    """Record each round's decision as the program makes it.
-
-    The trainer looks ``repro.core.joint.proposed_scheme`` up at every
-    round; ``first_rounds`` fails where a round made no call to it, as
-    nothing would then be compared.
-    """
-    from repro.core import joint
-
-    seen = []
-    scheme = joint.proposed_scheme
-
-    def recorded(*args, **kw):
-        dec = scheme(*args, **kw)
-        seen.append(dec)
-        return dec
-
-    joint.proposed_scheme = recorded
-    try:
-        yield seen
-    finally:
-        joint.proposed_scheme = scheme
 
 
 def first_rounds(trainer, params0) -> correct.Observed:
     """Drive the trainer's first rounds through ``run_round``; return
-    what they produced."""
+    what they produced, each round's decision as ``RoundMetrics``
+    reports the one it applied."""
     states, metrics = [], []
-    with observe() as seen:
-        for i in range(SETUP_ROUNDS):
-            metrics.append(trainer.run_round(i, eval_now=False))
-            jax.block_until_ready(trainer.params)
-            states.append(trainer.opt_state)
-    if len(seen) != SETUP_ROUNDS:
+    for i in range(SETUP_ROUNDS):
+        metrics.append(trainer.run_round(i, eval_now=False))
+        jax.block_until_ready(trainer.params)
+        states.append(trainer.opt_state)
+    seen = [m.decision for m in metrics]
+    if any(d is None for d in seen):
         raise RuntimeError(
-            f"{len(seen)} calls of repro.core.joint.proposed_scheme in "
-            f"{SETUP_ROUNDS} rounds: the program's decisions cannot be "
-            f"read, so they cannot be checked")
+            "a round's RoundMetrics carries no decision: the program's "
+            "decisions cannot be read, so they cannot be checked")
     first = next((s for s in states if int(s.count) == 1), None)
     return correct.Observed(
         rho=[d.rho for d in seen], p=[d.p for d in seen],
@@ -262,18 +294,20 @@ def round_failed(m) -> bool:
     return bool(m.fallbacks != () or not m.feasible or m.skipped_update)
 
 
-def _stage_rows(tele, rounds: range) -> Dict[int, tuple]:
-    """round -> (round_t0_s, round dur_s, [(stage, t0_s, dur_s), ...])."""
+def _round_trees(tele, rounds: range) -> Dict[int, Any]:
+    """round -> its ``round`` span tree."""
     from repro.obs.spans import build_tree
 
     roots, _ = build_tree(tele.events)
-    out = {}
-    for r in roots:
-        if r.name == "round" and r.round in rounds:
-            out[r.round] = (r.t0_s, r.dur_s,
-                            [(c.name, c.t0_s, c.dur_s) for c in r.children
-                             if c.kind == "stage"])
-    return out
+    return {r.round: r for r in roots
+            if r.name == "round" and r.round in rounds}
+
+
+def _stage_rows(trees: Dict[int, Any]) -> Dict[int, tuple]:
+    """round -> (round_t0_s, round dur_s, [(stage, t0_s, dur_s), ...])."""
+    return {i: (r.t0_s, r.dur_s, [(c.name, c.t0_s, c.dur_s)
+                                  for c in r.children if c.kind == "stage"])
+            for i, r in trees.items()}
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
@@ -296,7 +330,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     print(f"cell {workload}: seed={seed} seconds={seconds} trace={int(trace)}"
           f" device={dev.device_kind} x{len(devices)} cache={cache_dir}",
           file=sys.stderr)
-    cfg = cell.cfg
+    cfg, model = cell.cfg, cell.model
     sub = inputs.seeds(seed)
     tele = obs.Telemetry() if trace else obs.NULL
     data, params0, trainer = build(cell, sub, tele)
@@ -333,15 +367,16 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         file=sys.stderr)
 
     ctx = Context(setup_s=setup_s, walls=walls,
-                  window_s=t_end - t_open)
+                  window_s=t_end - t_open, cfg=cfg, model=model)
     attempted = len(walls) + (error is not None)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices)}
     breakdown = None
     if trace and error is None:
-        rows = _stage_rows(tele, window_rounds)
+        trees = _round_trees(tele, window_rounds)
+        ctx.trees = list(trees.values())
         ctx.spans = [{"round": r[1], **_sum_stages(r[2])}
-                     for r in rows.values()]
+                     for r in _stage_rows(trees).values()]
         # without a chip there is no device plane to profile
         prof = None
         if require_chip:
@@ -350,9 +385,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             failed += failed_p
         if prof is not None:
             ctx.profile = prof
+            ctx.profile_selected = [m.n_selected for m in prof_metrics]
             ctx.profile_flops = float(sum(
-                flops.round_flops(cfg, m.n_selected) for m in prof_metrics))
-            ctx.peak_flops = peaks.peak(dev.device_kind)["flops"]
+                model.round_flops(cfg, n) for n in ctx.profile_selected))
+            peak = peaks.peak(dev.device_kind)
+            ctx.peak_flops = peak["flops"]
+            ctx.peak_bytes_per_s = peak["hbm_bytes_per_s"]
             device["busy_s"] = prof["busy_s"]
             device["window_s"] = prof["window_s"]
             breakdown = {"device_ops": prof["device_ops"],
@@ -370,7 +408,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     del trainer, tele
     gc.collect()
     log("reference: start")
-    ref = reference.Reference(cfg, cell.traffic, sub).run(
+    ref = reference.Reference(cfg, cell.traffic, sub, model).run(
         data, params0, rounds=SETUP_ROUNDS, selections=observed.delta)
     log("reference: done")
     numbers = correct.compare(observed, ref)
@@ -427,11 +465,11 @@ def _profile(trainer, first_round: int, tele):
         log(f"profile: {len(ms)} rounds traced")
         if error is not None:
             return None, ms, failed, error
-        devices, steps = trace_reduce.read_xplane(
+        devices, programs, steps = trace_reduce.read_xplane(
             trace_reduce.find_xplane(log_dir), STEP_NAME)
-        rows = _stage_rows(tele, range(first_round, i))
+        rows = _stage_rows(_round_trees(tele, range(first_round, i)))
         spans = {k: (v[0], v[2]) for k, v in rows.items()}
-        out = trace_reduce.reduce(devices, steps, spans)
+        out = trace_reduce.reduce(devices, steps, spans, programs=programs)
         log(f"profile: reduced {sum(map(len, devices.values()))} device "
             f"events")
         return out, ms, failed, None

@@ -36,12 +36,12 @@ def _cell(workload):
 def test_control_is_not_correct(workload):
     cell = _cell(workload)
     sub = inputs.seeds(11)
-    data = inputs.placement(cell.cfg, sub["data"])
-    params0 = inputs.make_params(cell.cfg, sub["weights"])
-    ctl = reference.Reference(cell.cfg, cell.traffic, sub,
+    data = cell.model.samples(cell.cfg, sub["data"])
+    params0 = cell.model.init_params(cell.cfg, sub["weights"])
+    ctl = reference.Reference(cell.cfg, cell.traffic, sub, cell.model,
                               dtype=jnp.bfloat16, precision=None)
     got = calibrate.as_observed(ctl.run(data, params0))
-    ref = reference.Reference(cell.cfg, cell.traffic, sub).run(
+    ref = reference.Reference(cell.cfg, cell.traffic, sub, cell.model).run(
         data, params0, selections=got.delta)
     ok, _ = correct.judge(correct.compare(got, ref), correct.limits(workload))
     assert not ok

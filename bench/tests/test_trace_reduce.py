@@ -2,9 +2,11 @@ import os
 
 import pytest
 
+import record_spans
 import trace_reduce as tr
 
 TRACE = os.path.join(os.path.dirname(__file__), "data", "tiny.xplane.pb")
+SPANS = os.path.join(os.path.dirname(__file__), "data", "spans.xplane.pb")
 
 
 def test_union_and_gaps():
@@ -33,7 +35,7 @@ def test_reduce_attributes_idle_time_to_stages():
 def test_recorded_trace():
     """A trace recorded on a TPU v5e by record_trace.py: three steps of
     two small programs with host pauses between them."""
-    devices, steps = tr.read_xplane(TRACE, "feel_round")
+    devices, _, steps = tr.read_xplane(TRACE, "feel_round")
     assert [s[2] for s in steps] == [0, 1, 2]
     assert devices and all(devices.values())
     out = tr.reduce(devices, steps)
@@ -45,3 +47,25 @@ def test_recorded_trace():
     assert out["window_s"] - out["busy_s"] > 3 * 0.002
     assert sum(v for _, v in out["device_ops"]) >= out["busy_s"] * 0.99
     assert dict(out["idle_gaps"]).keys() <= {"round_self", "between_rounds"}
+
+
+def test_recorded_trace_device_seconds_by_op_and_program():
+    """``record_spans.py``'s trace: its ``round`` annotations as the
+    steps; the programs are the two jitted lambdas and the eager sum and
+    add."""
+    devices, programs, steps = tr.read_xplane(SPANS, "round")
+    assert len(steps) == record_spans.ROUNDS
+    out = tr.reduce(devices, steps, programs=programs, top=3)
+    assert set(out["programs_s"]) == {"jit__lambda", "jit__reduce_sum",
+                                      "jit_add"}
+    assert all(v > 0 for v in out["programs_s"].values())
+    # every op runs inside a program
+    assert sum(out["programs_s"].values()) >= out["busy_s"]
+    # device_ops is the head of the full per-op table
+    assert len(out["ops_s"]) > len(out["device_ops"]) == 3
+    assert dict(out["device_ops"]) == {k: out["ops_s"][k]
+                                       for k, _ in out["device_ops"]}
+    assert min(v for _, v in out["device_ops"]) >= max(
+        v for k, v in out["ops_s"].items()
+        if k not in dict(out["device_ops"]))
+    assert sum(out["ops_s"].values()) >= out["busy_s"] * 0.99

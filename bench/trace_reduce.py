@@ -1,5 +1,6 @@
 """From a profiler trace (``.xplane.pb``) to device busy time, idle share,
-the top device operations and the idle gaps named by program stage.
+the top device operations, the idle gaps named by program stage, and
+device seconds by operation and by XLA program.
 
 The traced sub-window is the span from the start of the first to the end
 of the last host step annotation (``jax.profiler.StepTraceAnnotation``,
@@ -9,7 +10,10 @@ the devices. Each idle gap is attributed to what the host was doing: the
 program stage whose span covers it, ``round_self`` inside a round but
 outside its stages, or ``between_rounds``. Stage spans come from the
 program's telemetry clock; the offset to the profiler's clock is taken
-per round from the step annotation that wraps it.
+per round from the step annotation that wraps it. A device's ``XLA
+Modules`` line holds one event per program execution, named
+``jit_<function>(<fingerprint>)``; a program's seconds are summed over
+its fingerprints under ``jit_<function>``.
 """
 from __future__ import annotations
 
@@ -24,6 +28,7 @@ Interval = Tuple[int, int]
 
 _DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 _OP_LINES = ("XLA Ops", "XLA Modules")
+_MODULE_LINE = "XLA Modules"
 
 
 def find_xplane(log_dir: str) -> str:
@@ -35,23 +40,31 @@ def find_xplane(log_dir: str) -> str:
     return paths[0]
 
 
+def _events(line, sep: str) -> List[Tuple[int, int, str]]:
+    return [] if line is None else [
+        (int(e.start_ns), int(e.end_ns), e.name.split(sep)[0])
+        for e in line.events]
+
+
 def read_xplane(path: str, step_name: str):
-    """``(device_ops, steps)`` of a trace: per device plane the
-    ``(start_ns, end_ns, op name)`` of its operations, and the host's
-    ``(start_ns, end_ns, step_num)`` step annotations."""
+    """``(device_ops, device_programs, steps)`` of a trace: per device
+    plane the ``(start_ns, end_ns, op name)`` of its operations and the
+    ``(start_ns, end_ns, program name)`` of its program executions, and
+    the host's ``(start_ns, end_ns, step_num)`` step annotations."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
     devices: Dict[str, List[Tuple[int, int, str]]] = {}
+    programs: Dict[str, List[Tuple[int, int, str]]] = {}
     steps: List[Tuple[int, int, int]] = []
     for plane in data.planes:
         if _DEVICE_PLANE.match(plane.name):
             lines = {line.name: line for line in plane.lines}
             line = next((lines[n] for n in _OP_LINES if n in lines), None)
             # "%fusion.6 = f32[...] fusion(...)": keep the op's own name
-            devices[plane.name] = [] if line is None else [
-                (int(e.start_ns), int(e.end_ns), e.name.split(" = ")[0])
-                for e in line.events]
+            devices[plane.name] = _events(line, " = ")
+            # "jit_local_grads(1234...)": keep the function's name
+            programs[plane.name] = _events(lines.get(_MODULE_LINE), "(")
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
@@ -60,7 +73,7 @@ def read_xplane(path: str, step_name: str):
                         steps.append((int(e.start_ns), int(e.end_ns),
                                       int(stats.get("step_num", -1))))
     steps.sort()
-    return devices, steps
+    return devices, programs, steps
 
 
 def union(intervals: Iterable[Interval]) -> List[Interval]:
@@ -113,8 +126,12 @@ def _labels(steps, stage_spans, lo, hi):
 def reduce(devices: Dict[str, List[Tuple[int, int, str]]],
            steps: List[Tuple[int, int, int]],
            stage_spans: Optional[Dict[int, tuple]] = None,
-           top: int = 10) -> dict:
-    """Busy/idle of the stepped sub-window, top ops, idle by stage.
+           top: int = 10,
+           programs: Optional[Dict[str, List[Tuple[int, int, str]]]] = None
+           ) -> dict:
+    """Busy/idle of the stepped sub-window, top ops, idle by stage, and
+    the seconds of every op (``ops_s``) and of every program
+    (``programs_s``, from ``programs``) in it, averaged over devices.
 
     ``stage_spans`` maps a step number to ``(round_t0_s, [(stage,
     t0_s, dur_s), ...])`` on the telemetry clock.
@@ -131,6 +148,11 @@ def reduce(devices: Dict[str, List[Tuple[int, int, str]]],
     busy_ns = 0
     ops: Dict[str, float] = collections.defaultdict(float)
     idle: Dict[str, float] = collections.defaultdict(float)
+    progs: Dict[str, float] = collections.defaultdict(float)
+    for events in (programs or {}).values():
+        for s, e, name in events:
+            if e > lo and s < hi:
+                progs[name] += (min(e, hi) - max(s, lo)) / 1e9
     for events in devices.values():
         inside = [(max(s, lo), min(e, hi), name) for s, e, name in events
                   if e > lo and s < hi]
@@ -156,6 +178,8 @@ def reduce(devices: Dict[str, List[Tuple[int, int, str]]],
         "steps": len(steps),
         "device_ops": [[k, v / n] for k, v in sorted(
             ops.items(), key=lambda kv: -kv[1])[:top]],
+        "ops_s": {k: v / n for k, v in ops.items()},
+        "programs_s": {k: v / n for k, v in progs.items()},
         "idle_gaps": [[k, v / n] for k, v in sorted(
             idle.items(), key=lambda kv: -kv[1])[:top]],
     }
