@@ -16,6 +16,7 @@ ARCHS: List[str] = [
     "qwen2-vl-2b",
     "deepseek-v3-671b",
     "deepseek-v2-236b",
+    "deepseek-v2-lite",
     "stablelm-12b",
     "command-r-35b",
     "recurrentgemma-9b",

@@ -1,8 +1,12 @@
 """DeepSeek-V2 236B [arXiv:2405.04434].
 
-60L, d_model=5120, 128 heads, MLA (q_lora=3072, kv_lora=512), vocab=102400.
-MoE: 2 shared + 160 routed experts, top-6, per-expert d_ff=1536;
-first layer dense (d_ff=12288).  Optimizer: adafactor (HBM).
+60L, d_model=5120, 128 heads, MLA (q_lora=1536, kv_lora=512), YaRN rope
+(factor 40, mscale 0.707), vocab=102400.
+MoE: 2 shared + 160 routed experts, top-6 softmax routing, gates not
+renormalized and scaled by 16, per-expert d_ff=1536; first layer dense
+(d_ff=12288).  Group-limited routing (n_group 8, topk_group 3: a token's
+experts come from its 3 best of 8 expert groups) is not implemented;
+routing is greedy top-6 over all 160.  Optimizer: adafactor (HBM).
 """
 from repro.models.config import ArchConfig
 
@@ -12,8 +16,11 @@ CONFIG = ArchConfig(
     d_ff=12288, vocab=102400,
     layer_pattern=("mla",), first_dense=1,
     n_experts=160, n_shared_experts=2, topk=6, moe_d_ff=1536,
-    q_lora=3072, kv_lora=512, qk_nope_dim=128, qk_rope_dim=64,
+    norm_topk_prob=False, routed_scaling_factor=16.0,
+    q_lora=1536, kv_lora=512, qk_nope_dim=128, qk_rope_dim=64,
     v_head_dim=128,
+    rope_factor=40.0, rope_original_max=4096, rope_beta_fast=32.0,
+    rope_beta_slow=1.0, rope_mscale=0.707, rope_mscale_all_dim=0.707,
     optimizer="adafactor", citation="arXiv:2405.04434",
 )
 

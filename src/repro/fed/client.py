@@ -12,6 +12,10 @@
       TPU version).
 * ``local_gradient`` — eq. (4): gradient of the loss averaged over the
   *selected* subset M_k (selection mask delta).
+* ``sequence_sigma`` / ``sequence_local_gradient`` — the same for token
+  sequences (``models.control_lm``): a sample's loss is the mean over
+  its positions, and sigma is the exact squared norm of the (bias-free)
+  output layer's gradient of that loss.
 """
 from __future__ import annotations
 
@@ -88,3 +92,28 @@ def _per_sample_loss(params, images, labels, loss_fn):
     it per sample through vmap."""
     return jax.vmap(lambda img, lab: loss_fn(params, img[None],
                                              lab[None]))(images, labels)
+
+
+def sequence_sigma(h: Array, r: Array) -> Array:
+    """sigma of each sequence, ``||(1/S) H^T R||_F^2`` for features
+    ``h`` (B, S, d) and logit residuals ``r`` (B, S, V), in Gram form:
+    ``sum_{t,t'} (h_t . h_t') (r_t . r_t') / S^2`` (O(S^2 (d + V))
+    instead of O(S d V))."""
+    S = h.shape[1]
+    gh = jnp.einsum("bsd,btd->bst", h, h)
+    gr = jnp.einsum("bsv,btv->bst", r, r)
+    return jnp.sum(gh * gr, axis=(1, 2)) / (S * S)
+
+
+def sequence_local_gradient(params, x: Array, labels: Array, delta: Array,
+                            model):
+    """eq. (4) over token sequences: ``(grad, slots)``, the gradient of
+    the delta-weighted mean of the sequences' losses and the held
+    experts' slots of each sequence (layers, B, n_held)."""
+
+    def weighted_loss(p):
+        loss, slots = model.per_sample_loss(p, x, labels)
+        return (jnp.sum(delta * loss)
+                / jnp.maximum(jnp.sum(delta), 1e-9)), slots
+
+    return jax.grad(weighted_loss, has_aux=True)(params)

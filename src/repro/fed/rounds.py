@@ -11,6 +11,15 @@ Each round:
      footnote 4 runs multiple local steps and uploads model deltas;
   5. the server aggregates with inverse-propensity weights (eq. 19)
      and applies the optimizer update (eq. 20; Adam in §VI-A).
+
+The programs follow the model and its size.  A model over token
+sequences (``sequence = True``, ``models.control_lm``) scores its
+sequences device by device (``lax.map``), so the (K·|D̂|, S, V) logits
+never exist at once.  Where K gradient trees would take more than
+``GRAD_TREES_SHARE`` of the device's memory, the local gradients are a
+``lax.scan`` over devices that adds each device's eq. (4) gradient,
+weighted by eq. (19), into one tree; the server step applies the
+optimizer to that sum.
 """
 from __future__ import annotations
 
@@ -39,6 +48,23 @@ Array = jax.Array
 
 #: checkpoint file prefix inside a checkpoint directory.
 CKPT_NAME = "feel_ckpt"
+#: largest share of the device's memory that K per-device gradient
+#: trees may take; above it the gradients are summed device by device
+GRAD_TREES_SHARE = 0.25
+#: the device memory assumed where the backend reports none (the CPU):
+#: one TPU v5e chip
+DEFAULT_DEVICE_BYTES = 16 * 2 ** 30
+
+
+def device_bytes() -> int:
+    """Memory of the default device, as its backend reports it."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", DEFAULT_DEVICE_BYTES))
+
+
+def tree_bytes(tree) -> int:
+    return sum(int(np.prod(x.shape)) * jnp.dtype(x.dtype).itemsize
+               for x in jax.tree.leaves(tree))
 
 
 @dataclasses.dataclass
@@ -178,12 +204,39 @@ class FEELTrainer:
                        "momentum": optim.momentum,
                        "adafactor": optim.adafactor}[cfg.optimizer]
         self.opt = opt_builder(cfg.lr)
+        self._sequence = bool(getattr(model, "sequence", False))
+        grad_trees = sys.K * tree_bytes(params)
+        #: the local gradients are summed device by device
+        self._stream = grad_trees > GRAD_TREES_SHARE * device_bytes()
+        if self._stream and (self._resilient or cfg.local_steps > 1):
+            raise ValueError(
+                f"K={sys.K} gradient trees take {grad_trees / 1e9:.2f} GB, "
+                f"more than {GRAD_TREES_SHARE:.0%} of the device's "
+                f"{device_bytes() / 1e9:.2f} GB, so the gradients are "
+                "summed device by device; the resilience layer and "
+                "local_steps > 1 need per-device trees")
         self.opt_state = self.opt.init(params)
         self._build_jitted()
 
+    @property
+    def opt_state(self):
+        """The optimizer state.  Where the gradients are summed device by
+        device the server step updates the state in place (its buffers
+        are donated), so a caller gets a host copy to keep."""
+        if self._stream:
+            return jax.device_get(self._opt_state)
+        return self._opt_state
+
+    @opt_state.setter
+    def opt_state(self, state):
+        self._opt_state = (jax.device_put(state) if self._stream
+                           else state)
+
     # ------------------------------------------------------------------
     def _build_jitted(self):
-        model, cfg = self.model, self.cfg
+        # the programs hold no reference to the trainer, so that a
+        # trainer let go frees its device arrays without a gc pass
+        model, cfg, opt = self.model, self.cfg, self.opt
 
         if cfg.sigma_method == "last_layer_kernel":
             @jax.jit
@@ -251,14 +304,96 @@ class FEELTrainer:
                 grads = jax.tree.map(scrub, grads)
             g_hat = server_mod.aggregate_gradients(sys, grads, alpha,
                                                    renormalize=renormalize)
-            updates, opt_state = self.opt.update(g_hat, opt_state, params)
+            updates, opt_state = opt.update(g_hat, opt_state, params)
             return optim.apply_updates(params, updates), opt_state, g_hat
 
-        self._sigma_all = sigma_all
-        self._local_grads = local_grads
+        if self._sequence:
+            self._build_sequence_jitted()
+        else:
+            self._sigma_all = sigma_all
+            self._local_grads = local_grads
+        if self._stream:
+            self._local_grads = self._streamed_local_grads()
         self._local_deltas = local_deltas
         self._server_step = jax.jit(server_step,
                                     static_argnames=("renormalize",))
+
+        def sum_step(params, opt_state, g_hat):
+            """The optimizer step (eq. 20) on the eq. (19) sum."""
+            updates, opt_state = opt.update(g_hat, opt_state, params)
+            return optim.apply_updates(params, updates), opt_state
+
+        self._sum_step = jax.jit(sum_step, donate_argnums=(1, 2))
+
+    def _device_grad(self):
+        """One device's eq. (4) gradient: ``(grad, slots)``, the slots
+        (layers, D̂, n_held) of a sequence model's held experts, else
+        None."""
+        model = self.model
+        if self._sequence:
+            return functools.partial(client_mod.sequence_local_gradient,
+                                     model=model)
+
+        def device_grad(params, x, labels, delta):
+            return client_mod.local_gradient(params, x, labels, delta,
+                                             model.loss_fn), None
+
+        return device_grad
+
+    def _build_sequence_jitted(self):
+        """sigma and per-device gradients of a model over token sequences,
+        each returning the held experts' slots besides."""
+        model, device_grad = self.model, self._device_grad()
+
+        @jax.jit
+        def sigma_all(params, x, labels):
+            """(K, D̂) sigma and the slots (layers, n_held) of every
+            scored sequence, one device at a time."""
+            def one(args):
+                h, r, slots = model.head_residuals(params, *args)
+                return client_mod.sequence_sigma(h, r), jnp.sum(slots, 1)
+
+            sigma, slots = jax.lax.map(one, (x, labels))
+            return sigma, jnp.sum(slots, axis=0)
+
+        @jax.jit
+        def local_grads(params, x, labels, delta):
+            """(per-device gradient trees, slots (K, layers, D̂,
+            n_held))."""
+            return jax.vmap(lambda *a: device_grad(params, *a))(
+                x, labels, delta)
+
+        self._sigma_all = sigma_all
+        self._local_grads = local_grads
+
+    def _streamed_local_grads(self):
+        """The local gradients where K trees do not fit: ``(eq. 19 sum
+        of the eq. 4 gradients, slots (K, ...) or None)``, one device at
+        a time.  Every device's gradient is computed, a device of weight
+        0 adding nothing and counting no slots, so that a round's device
+        work does not follow how many devices upload."""
+        K, device_grad = self.sys.K, self._device_grad()
+
+        @jax.jit
+        def local_grads(params, sys, alpha, x, labels, delta):
+            """The weights are eq. (19)'s as ``server.aggregate_gradients``
+            gives them (looked up when traced)."""
+            with jax.default_matmul_precision("float32"):
+                w = server_mod.aggregate_gradients(
+                    sys, jnp.eye(K, dtype=jnp.float32), alpha)
+
+            def step(acc, args):
+                wk, xk, lk, dk = args
+                g, slots = device_grad(params, xk, lk, dk)
+                acc = jax.tree.map(lambda a, b: a + wk.astype(b.dtype) * b,
+                                   acc, g)
+                return acc, jax.tree.map(
+                    lambda s: jnp.where(wk != 0, s, 0), slots)
+
+            acc = jax.tree.map(jnp.zeros_like, params)
+            return jax.lax.scan(step, acc, (w, x, labels, delta))
+
+        return local_grads
 
     # ------------------------------------------------------------------
     def _gather_round_batches(self):
@@ -299,8 +434,12 @@ class FEELTrainer:
         if tele.profile:
             self._profile_once("sigma_all", "sigma", self._sigma_all,
                                (self.params, images, labels), tele, i)
+        sigma_slots = grad_slots = None
         with tele.stage("sigma"):
-            sigma = tele.block(self._sigma_all(self.params, images, labels))
+            sigma = self._sigma_all(self.params, images, labels)
+            if self._sequence:
+                sigma, sigma_slots = sigma
+            sigma = tele.block(sigma)
         with tele.span("round.draws"):
             h = jax.random.exponential(kh, (sys.K, sys.N)) * 1e-5
             alpha = (jax.random.uniform(ka, (sys.K,)) < sys.eps
@@ -371,14 +510,17 @@ class FEELTrainer:
             else:
                 self._profile_once(
                     "local_grads", "local_grads", self._local_grads,
-                    (self.params, images, labels, delta), tele, i)
+                    self._local_grads_args(images, labels, delta,
+                                           uploaded), tele, i)
         with tele.stage("local_grads"):
             if cfg.local_steps > 1:
                 grads = self._local_deltas(self.params, images, labels,
                                            delta, jnp.asarray(cfg.lr))
             else:
-                grads = self._local_grads(self.params, images, labels,
-                                          delta)
+                grads = self._local_grads(*self._local_grads_args(
+                    images, labels, delta, uploaded))
+                if self._sequence or self._stream:
+                    grads, grad_slots = grads
             grads = tele.block(grads)
 
         # ---- fault application + resilience policies ------------------
@@ -423,6 +565,16 @@ class FEELTrainer:
                     reg0.counter("feel_rounds_skipped_total",
                                  "rounds whose optimizer update was "
                                  "skipped (no usable upload)").inc()
+            elif self._stream:
+                # grads is already the eq. (19) sum; it and the optimizer
+                # state are donated to the step
+                if self.monitor is not None:
+                    g_norm_sq = float(sum(jnp.vdot(x, x)
+                                          for x in jax.tree.leaves(grads)))
+                params, self._opt_state = self._sum_step(
+                    self.params, self._opt_state, grads)
+                del grads
+                self.params = tele.block(params)
             else:
                 params, self.opt_state, g_hat = self._server_step(
                     sys, self.params, self.opt_state, grads, alpha_agg,
@@ -461,6 +613,10 @@ class FEELTrainer:
                                          wall_s)
                 if tele.enabled and reg.enabled:
                     tele.emit(reg.snapshot_event(round=i))
+                if tele.enabled and sigma_slots is not None:
+                    self._record_load(tele, sigma_slots, grad_slots, sel,
+                                      np.asarray(uploaded) > 0,
+                                      images.shape[-1])
         if self.monitor is not None:
             stage_s = None
             if tele.enabled:
@@ -493,6 +649,30 @@ class FEELTrainer:
                             fallbacks=dec.fallbacks,
                             feasible=bool(dec.feasible),
                             decision=dec)
+
+    def _local_grads_args(self, images, labels, delta, uploaded):
+        if self._stream:
+            return (self.params, self.sys, uploaded, images, labels, delta)
+        return (self.params, images, labels, delta)
+
+    def _record_load(self, tele, sigma_slots, grad_slots, sel: np.ndarray,
+                     uploaded: np.ndarray, seq_len: int) -> None:
+        """The held experts' load of the round as the ``moe.load`` span's
+        attributes: slots per layer and held expert of the scored
+        sequences and of those with a nonzero eq. (19) weight (selected,
+        of a live device that uploaded), and the tokens behind each.
+        The counts come to the host here, under ``telemetry``."""
+        weighted = sel & (uploaded & self._ipw_live)[:, None]   # (K, D̂)
+        grad = np.zeros(np.shape(sigma_slots), np.int64)
+        if grad_slots is not None:
+            grad = np.einsum("kldh,kd->lh", np.asarray(grad_slots, np.int64),
+                             weighted.astype(np.int64))
+        with tele.span("moe.load",
+                       sigma_slots=np.asarray(sigma_slots).tolist(),
+                       grad_slots=grad.tolist(),
+                       tokens_scored=int(sel.size * seq_len),
+                       tokens_grad=int(weighted.sum() * seq_len)):
+            pass
 
     def _profile_once(self, name: str, stage: str, fn, args, tele,
                       round_i: int) -> None:

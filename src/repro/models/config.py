@@ -46,6 +46,15 @@ class ArchConfig:
     qk_norm: bool = False             # gemma3
     mrope_sections: Tuple[int, ...] = ()  # qwen2-vl M-RoPE (t, h, w) split
     attn_logit_softcap: float = 0.0
+    # YaRN rope scaling of the MLA rope dims (DeepSeek-V2); a factor of
+    # 1 is plain rope.  mscale / mscale_all_dim set the cos/sin scale
+    # mscale(f, m)/mscale(f, m_all) and the softmax scale mscale(f, m_all)^2
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     # --- MoE ------------------------------------------------------------
     n_experts: int = 0
@@ -55,6 +64,13 @@ class ArchConfig:
     first_dense: int = 0          # leading dense layers (DeepSeek)
     capacity_factor: float = 1.25
     router_aux_weight: float = 1e-3
+    norm_topk_prob: bool = True   # renormalize the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0   # times the routed experts' gates
+    # (first id, count) of the routed experts this chip holds: the layer
+    # routes over all n_experts and computes only its own experts' part,
+    # dropping no token (moe.held_expert_ffn).  None holds all of them
+    # in the capacity-based layer of the launch path (moe.moe_ffn).
+    experts_held: Optional[Tuple[int, int]] = None
 
     # --- MLA ------------------------------------------------------------
     q_lora: int = 0
@@ -103,6 +119,12 @@ class ArchConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def n_held(self) -> int:
+        """Routed experts this chip holds."""
+        return (self.experts_held[1] if self.experts_held is not None
+                else self.n_experts)
+
+    @property
     def ssm_d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -136,6 +158,10 @@ class ArchConfig:
             assert self.kv_lora > 0
         if self.n_experts:
             assert self.topk > 0 and self.moe_d_ff > 0
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            assert 0 <= first and count > 0 and \
+                first + count <= self.n_experts
         if "attn_local" in self.layer_pattern:
             assert self.window > 0
         if self.modality == "vlm":

@@ -10,10 +10,12 @@ so cost_analysis sees real FLOPs).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .config import ArchConfig
 
@@ -38,13 +40,63 @@ def init_dense(key, d_in: int, d_out: int, dtype) -> Array:
 
 # ------------------------------------------------------------------- rope
 
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature factor ``0.1 m ln s + 1`` (1 for
+    ``s <= 1``), DeepSeek-V2's ``yarn_get_mscale``."""
+    if scale <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float,
+                  beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies of a ``dim``-wide rotary head
+    (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``): pair i blends
+    ``1/(factor θ^(2i/d))`` (interpolated) into ``1/θ^(2i/d)``
+    (extrapolated) by a linear ramp over the correction range of
+    ``beta_fast``/``beta_slow`` rotations at ``original_max`` positions.
+    """
+    def correction_dim(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(dim // 2, dtype=np.float64)
+    extra = 1.0 / theta ** (2 * i / dim)
+    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp            # 1 where a pair keeps its frequency
+    return (extra / factor * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def rope_yarn(cfg: ArchConfig) -> Optional[tuple]:
+    """``(inv_freq, cos/sin scale)`` of the config's YaRN rope, or None
+    where it has no rope scaling."""
+    if cfg.rope_factor <= 1:
+        return None
+    inv = yarn_inv_freq(cfg.qk_rope_dim, cfg.rope_theta, cfg.rope_factor,
+                        cfg.rope_original_max, cfg.rope_beta_fast,
+                        cfg.rope_beta_slow)
+    scale = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+             / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    return inv, scale
+
+
 def _rope_cos_sin(positions: Array, n_pairs: int, theta: float,
-                  mrope_sections: Tuple[int, ...] = ()):
-    """cos/sin tables: positions (B,S) or (B,3,S) for M-RoPE.
+                  mrope_sections: Tuple[int, ...] = (),
+                  yarn: Optional[tuple] = None):
+    """cos/sin tables: positions (B,S) or (B,3,S) for M-RoPE; ``yarn``
+    (from ``rope_yarn``) replaces the frequencies and scales the tables.
 
     Returns (B, S, n_pairs) float32 cos and sin.
     """
-    freqs = theta ** (-jnp.arange(n_pairs, dtype=jnp.float32) / n_pairs)
+    if yarn is not None:
+        freqs = jnp.asarray(yarn[0])
+    else:
+        freqs = theta ** (-jnp.arange(n_pairs, dtype=jnp.float32) / n_pairs)
     if positions.ndim == 2:  # standard 1-D rope
         ang = positions[..., None].astype(jnp.float32) * freqs
     else:
@@ -57,20 +109,25 @@ def _rope_cos_sin(positions: Array, n_pairs: int, theta: float,
             total_repeat_length=n_pairs)  # (n_pairs,) in {0,1,2}
         pos = jnp.take(positions, sec_id, axis=1)  # (B, n_pairs, S)
         ang = jnp.swapaxes(pos, 1, 2).astype(jnp.float32) * freqs
+    if yarn is not None and yarn[1] != 1.0:
+        return jnp.cos(ang) * yarn[1], jnp.sin(ang) * yarn[1]
     return jnp.cos(ang), jnp.sin(ang)
 
 
 def apply_rope(x: Array, positions: Array, theta: float,
                fraction: float = 1.0,
-               mrope_sections: Tuple[int, ...] = ()) -> Array:
-    """x: (B, S, H, Dh). Rotates the first ``fraction * Dh`` dims."""
+               mrope_sections: Tuple[int, ...] = (),
+               yarn: Optional[tuple] = None) -> Array:
+    """x: (B, S, H, Dh). Rotates the first ``fraction * Dh`` dims, the
+    pairs laid out as halves (``rotate_half``)."""
     d = x.shape[-1]
     d_rot = int(d * fraction)
     d_rot -= d_rot % 2
     if d_rot == 0:
         return x
     n_pairs = d_rot // 2
-    cos, sin = _rope_cos_sin(positions, n_pairs, theta, mrope_sections)
+    cos, sin = _rope_cos_sin(positions, n_pairs, theta, mrope_sections,
+                             yarn)
     cos = cos[:, :, None, :]  # (B, S, 1, n_pairs)
     sin = sin[:, :, None, :]
     xr, xp = x[..., :d_rot], x[..., d_rot:]

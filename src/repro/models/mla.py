@@ -4,6 +4,12 @@ Queries and keys/values are produced through low-rank bottlenecks; the
 KV cache stores only the compressed latent c_kv (kv_lora dims) plus the
 shared rotary key k_rope — the paper-family's memory win for decode.
 
+The rope dims rotate as halves (``rotate_half``) in the layout the
+projections produce them; DeepSeek-V2's code first permutes them from
+interleaved pairs, which under random weights is a fixed permutation of
+``w_q``/``w_kr`` columns.  With ``rope_factor > 1`` the rope is YaRN
+(``layers.rope_yarn``) and the softmax scale carries its mscale^2.
+
 Two decode paths:
   * naive   — expand k/v from the cached latent every step (simple,
               verifiable against prefill);
@@ -21,7 +27,8 @@ import jax
 import jax.numpy as jnp
 
 from .config import ArchConfig
-from .layers import _NEG_INF, apply_rope, init_dense, rmsnorm
+from .layers import (_NEG_INF, apply_rope, init_dense, rmsnorm, rope_yarn,
+                     yarn_mscale)
 from .shard_ctx import constrain
 
 Array = jax.Array
@@ -57,7 +64,8 @@ def _queries(cfg: ArchConfig, p: dict, x: Array, positions: Array):
     else:
         q = (x @ p["w_q"]).reshape(B, S, H, nope + rope_d)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta,
+                        yarn=rope_yarn(cfg))
     return q_nope, q_rope
 
 
@@ -65,8 +73,18 @@ def _latents(cfg: ArchConfig, p: dict, x: Array, positions: Array):
     """Compressed latent (already normed) + roped shared key."""
     ckv = rmsnorm(x @ p["w_dkv"], p["kv_norm"])  # (B, S, kv_lora)
     kr = (x @ p["w_kr"])[:, :, None, :]  # (B, S, 1, rope_d)
-    kr = apply_rope(kr, positions, cfg.rope_theta)[:, :, 0, :]
+    kr = apply_rope(kr, positions, cfg.rope_theta,
+                    yarn=rope_yarn(cfg))[:, :, 0, :]
     return ckv, kr
+
+
+def softmax_scale(cfg: ArchConfig) -> float:
+    """``(nope + rope)^-0.5``, times YaRN's ``mscale(f, m_all)^2`` where
+    the rope is scaled (DeepSeek-V2: 192^-0.5 * 1.2608^2)."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.rope_factor > 1 and cfg.rope_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+    return scale
 
 
 def mla_attention(cfg: ArchConfig, p: dict, x: Array, positions: Array,
@@ -76,7 +94,7 @@ def mla_attention(cfg: ArchConfig, p: dict, x: Array, positions: Array,
     B, S, _ = x.shape
     H = cfg.n_heads
     nope, rope_d, vdim = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    scale = (nope + rope_d) ** -0.5
+    scale = softmax_scale(cfg)
     q_nope, q_rope = _queries(cfg, p, x, positions)
 
     if mode in ("train", "prefill"):

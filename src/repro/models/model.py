@@ -177,14 +177,18 @@ class FeelIntegration:
 
 # ------------------------------------------------------------ step fns
 
-def make_forward(cfg: ArchConfig):
+def make_forward(cfg: ArchConfig, with_slots: bool = False):
+    """forward(params, batch) -> (logits, hidden, aux); with
+    ``with_slots`` also the held-expert layers' slots (layers, B,
+    n_held)."""
     def forward(params, batch):
         x = embed_input(cfg, params, batch)
         B, S = x.shape[:2]
         pos = _positions(cfg, batch, B, S)
-        hidden, _, aux = apply_decoder(cfg, params["decoder"], x, pos,
-                                       mode="train")
-        return unembed(cfg, params, hidden), hidden, aux
+        out = apply_decoder(cfg, params["decoder"], x, pos, mode="train",
+                            with_slots=with_slots)
+        hidden, aux = out[0], out[2]
+        return (unembed(cfg, params, hidden), hidden, aux) + out[3:]
 
     return forward
 

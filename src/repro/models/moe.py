@@ -1,5 +1,19 @@
 """Mixture-of-Experts FFN (DeepSeek-V2/V3 style: shared + routed
-experts, token-choice top-k routing, normalized gates).
+experts, token-choice top-k routing; the top-k gates renormalized where
+``norm_topk_prob``, then times ``routed_scaling_factor``).
+
+Two layers:
+
+* ``held_expert_ffn``, the FEEL round's: the chip's share of an
+  expert-parallel layer.  It routes over all ``n_experts`` (the router
+  keeps its published width, in float32 at HIGHEST), keeps the
+  assignments to the experts it holds (``experts_held``), sorts them by
+  expert and runs one grouped product per projection over the held
+  experts' weights (the Pallas grouped matmul that ships with JAX,
+  ``megablox.gmm``), then un-sorts and weights by the gate.  No token is
+  dropped; what the absent experts would add is left out, as on a chip
+  of an expert-parallel deployment before its exchange.
+* ``moe_ffn``, the launch path's, below.
 
 TPU adaptation (DESIGN.md §2): dispatch is *capacity-based gather*
 rather than a (tokens x experts x capacity) one-hot einsum — each
@@ -17,6 +31,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import megablox
 
 from .config import ArchConfig
 from .layers import init_dense, mlp
@@ -26,22 +41,93 @@ Array = jax.Array
 
 
 def init_moe(key, cfg: ArchConfig, dtype) -> dict:
+    """Router over all ``n_experts``; weights of the held experts only."""
     d, E, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    Eh = cfg.n_held
     ks = jax.random.split(key, 5)
     scale = 1.0 / (d ** 0.5)
     p = {
         "router": init_dense(ks[0], d, E, jnp.float32),
-        "w_gate": (jax.random.normal(ks[1], (E, d, f), jnp.float32)
+        "w_gate": (jax.random.normal(ks[1], (Eh, d, f), jnp.float32)
                    * scale).astype(dtype),
-        "w_up": (jax.random.normal(ks[2], (E, d, f), jnp.float32)
+        "w_up": (jax.random.normal(ks[2], (Eh, d, f), jnp.float32)
                  * scale).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], (E, f, d), jnp.float32)
+        "w_down": (jax.random.normal(ks[3], (Eh, f, d), jnp.float32)
                    * (1.0 / f ** 0.5)).astype(dtype),
     }
     if cfg.n_shared_experts:
         from .layers import init_mlp
         p["shared"] = init_mlp(ks[4], d, cfg.n_shared_experts * f, dtype)
     return p
+
+
+def route(cfg: ArchConfig, router_logits: Array) -> Tuple[Array, Array]:
+    """Top-k gates and expert ids of float32 router logits (G, E)."""
+    probs = jax.nn.softmax(router_logits, axis=-1)
+    top_vals, top_idx = jax.lax.top_k(probs, cfg.topk)  # (G, K)
+    if cfg.norm_topk_prob:  # DeepSeek normalization
+        top_vals = top_vals / jnp.maximum(
+            jnp.sum(top_vals, -1, keepdims=True), 1e-9)
+    return top_vals * cfg.routed_scaling_factor, top_idx
+
+
+def _tile(n: int) -> int:
+    """A contraction or output tile of the grouped products: 512 wide
+    (a 128 x 512 x 512 step keeps the MXU busy where 128-wide steps are
+    mostly per-step overhead), the whole dimension where it is less."""
+    return min(512, n)
+
+
+def _grouped(x: Array, w: Array, sizes: Array) -> Array:
+    """Rows of x grouped by expert (``sizes`` rows each, the rest past
+    the groups) times each group's expert weights: (rows, w.shape[-1]).
+    Rows past the groups are not computed and read as garbage."""
+    tiling = (128, _tile(x.shape[1]), _tile(w.shape[2]))
+    return megablox.gmm(x, w, sizes, preferred_element_type=x.dtype,
+                        tiling=tiling,
+                        interpret=jax.default_backend() != "tpu")
+
+
+def held_expert_ffn(cfg: ArchConfig, p: dict, x: Array
+                    ) -> Tuple[Array, Array]:
+    """x: (B, S, d) -> (y, slots): y the held experts' part plus the
+    shared experts, ``slots`` (B, n_held) int32 the (token, held expert)
+    assignments of each sequence."""
+    B, S, d = x.shape
+    G, K = B * S, cfg.topk
+    first, Eh = cfg.experts_held or (0, cfg.n_experts)
+    xf = x.reshape(G, d)
+    router_logits = jnp.dot(xf.astype(jnp.float32),
+                            p["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+    gates, experts = route(cfg, router_logits)        # (G, K)
+    local = experts - first
+    held = (local >= 0) & (local < Eh)
+    seq = jnp.arange(G) // S
+    slots = jnp.zeros((B, Eh), jnp.int32).at[
+        seq[:, None], jnp.where(held, local, 0)].add(held.astype(jnp.int32))
+
+    # the held assignments sorted by expert, the others after them
+    group = jnp.where(held, local, Eh).reshape(-1)    # (G*K,)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.sum(slots, axis=0)
+    rows = -(-G * K // 128) * 128                     # 128-row tiles
+    pad = rows - G * K
+    order = jnp.concatenate([order, jnp.full((pad,), G * K, order.dtype)])
+    valid = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+    tok = jnp.minimum(order // K, G - 1)
+    xs = jnp.where(valid, xf[tok], 0)
+    act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
+    h = act(_grouped(xs, p["w_gate"], sizes)) \
+        * _grouped(xs, p["w_up"], sizes)
+    ys = jnp.where(valid, _grouped(h, p["w_down"], sizes), 0)
+    gate = jnp.concatenate([jnp.where(held, gates, 0.0).reshape(-1),
+                            jnp.zeros((1,), gates.dtype)])[order]
+    yf = jnp.zeros((G, d), x.dtype).at[tok].add(
+        ys * gate[:, None].astype(x.dtype))
+    if cfg.n_shared_experts:
+        yf = yf + mlp(p["shared"], xf, cfg.act)
+    return yf.reshape(B, S, d), slots
 
 
 def capacity(cfg: ArchConfig, n_tokens: int) -> int:
@@ -62,9 +148,7 @@ def moe_ffn(cfg: ArchConfig, p: dict, x: Array) -> Tuple[Array, Array]:
     router_logits = (xf @ p["router"].astype(xf.dtype)
                      ).astype(jnp.float32)  # (G, E)
     probs = jax.nn.softmax(router_logits, axis=-1)
-    top_vals, top_idx = jax.lax.top_k(probs, K)  # (G, K)
-    top_vals = top_vals / jnp.maximum(
-        jnp.sum(top_vals, -1, keepdims=True), 1e-9)  # DeepSeek normalization
+    top_vals, top_idx = route(cfg, router_logits)  # (G, K)
 
     # gate matrix (G, E): gate value where expert chosen, else 0
     gate_mat = jnp.zeros((G, E), jnp.float32).at[

@@ -25,7 +25,7 @@ from .config import ArchConfig
 from .layers import (apply_rope, causal_attend, decode_attend, init_attention,
                      init_mlp, local_attend_chunked, mlp, rmsnorm)
 from .mla import init_mla, mla_attention
-from .moe import init_moe, moe_ffn
+from .moe import held_expert_ffn, init_moe, moe_ffn
 from .rglru import init_rglru, rglru_mixer
 from .ssm import init_mamba, mamba_mixer
 from .shard_ctx import constrain
@@ -131,27 +131,31 @@ def _attn_apply(cfg: ArchConfig, kind: str, p: dict, x: Array,
 def apply_block(cfg: ArchConfig, kind: str, use_moe: bool, p: dict,
                 x: Array, positions: Array, mode: str, cache,
                 cache_index, mla_absorbed: bool = False
-                ) -> Tuple[Array, Any, Array]:
-    """Pre-norm residual block. Returns (x, new_cache, aux_loss)."""
+                ) -> Tuple[Array, Any, Array, Optional[Array]]:
+    """Pre-norm residual block. Returns (x, new_cache, aux_loss, slots):
+    ``slots`` (B, n_held) of a held-expert layer, else None."""
     aux = jnp.zeros((), jnp.float32)
+    slots = None
     h = rmsnorm(x, p["ln1"])
     if kind in ("attn", "attn_local", "mla"):
         y, new_cache = _attn_apply(cfg, kind, p, h, positions, mode, cache,
                                    cache_index, mla_absorbed)
     elif kind == "mamba":
         y, new_cache = mamba_mixer(cfg, p["mixer"], h, mode, cache)
-        return constrain(x + y, "act_btd"), new_cache, aux
+        return constrain(x + y, "act_btd"), new_cache, aux, slots
     elif kind == "rglru":
         y, new_cache = rglru_mixer(cfg, p["mixer"], h, mode, cache)
     else:
         raise ValueError(kind)
     x = x + y
     h = rmsnorm(x, p["ln2"])
-    if use_moe:
+    if use_moe and cfg.experts_held is not None:
+        f, slots = held_expert_ffn(cfg, p["ffn"], h)
+    elif use_moe:
         f, aux = moe_ffn(cfg, p["ffn"], h)
     else:
         f = mlp(p["ffn"], h, cfg.act)
-    return constrain(x + f, "act_btd"), new_cache, aux
+    return constrain(x + f, "act_btd"), new_cache, aux, slots
 
 
 # ----------------------------------------------------------- decoder stack
@@ -239,19 +243,22 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
 def apply_decoder(cfg: ArchConfig, params: dict, x: Array, positions: Array,
                   mode: str, cache: Optional[dict] = None,
                   cache_index: Array | int = 0,
-                  mla_absorbed: bool = False):
-    """Returns (hidden (B,S,d), new_cache, aux_loss_sum)."""
+                  mla_absorbed: bool = False, with_slots: bool = False):
+    """Returns (hidden (B,S,d), new_cache, aux_loss_sum), and with
+    ``with_slots`` also the held-expert layers' slots (layers, B,
+    n_held), in layer order."""
     head, n_body, pattern, tail = _layer_plan(cfg)
     use_moe = _uses_moe(cfg)
     aux_total = jnp.zeros((), jnp.float32)
     new_cache: dict = {"head": [], "body": {}, "tail": []}
+    slots: list = []
 
     blk = functools.partial(apply_block, cfg)
     # head layers: dense FFN even in MoE configs (DeepSeek lead-in)
     for i, kind in enumerate(head):
         c = cache["head"][i] if cache is not None else None
-        x, nc, aux = blk(kind, False, params["head"][i], x, positions, mode,
-                         c, cache_index, mla_absorbed)
+        x, nc, aux, sl = blk(kind, False, params["head"][i], x, positions,
+                             mode, c, cache_index, mla_absorbed)
         aux_total += aux
         new_cache["head"].append(nc)
 
@@ -266,15 +273,17 @@ def apply_decoder(cfg: ArchConfig, params: dict, x: Array, positions: Array,
             else:
                 p_slices, c_slices = xs, (None,) * len(pattern)
             aux_step = jnp.zeros((), jnp.float32)
-            ncs = []
+            ncs, sls = [], []
             for pos, kind in enumerate(pattern):
-                x, nc, aux = blk(kind, use_moe, p_slices[pos], x, positions,
-                                 mode, c_slices[pos], cache_index,
-                                 mla_absorbed)
+                x, nc, aux, sl = blk(kind, use_moe, p_slices[pos], x,
+                                     positions, mode, c_slices[pos],
+                                     cache_index, mla_absorbed)
                 aux_step += aux
                 ncs.append(nc)
+                if sl is not None:
+                    sls.append(sl)
             if mode == "train":
-                return x, aux_step
+                return x, (aux_step, tuple(sls))
             return x, (tuple(ncs), aux_step)
 
         if cfg.remat and mode == "train":
@@ -282,7 +291,12 @@ def apply_decoder(cfg: ArchConfig, params: dict, x: Array, positions: Array,
 
         unroll = n_body if cfg.unroll_layers else cfg.scan_unroll
         if mode == "train":
-            x, auxs = jax.lax.scan(step, x, body_params, unroll=unroll)
+            x, (auxs, body_slots) = jax.lax.scan(step, x, body_params,
+                                                 unroll=unroll)
+            # (repeats, B, n_held) per pattern position -> layer order
+            if body_slots:
+                slots.extend(jnp.stack(body_slots, axis=1).reshape(
+                    (-1,) + body_slots[0].shape[1:]))
         elif mode == "prefill":
             x, (nc_body, auxs) = jax.lax.scan(step, x, body_params,
                                               unroll=unroll)
@@ -300,11 +314,16 @@ def apply_decoder(cfg: ArchConfig, params: dict, x: Array, positions: Array,
 
     for i, kind in enumerate(tail):
         c = cache["tail"][i] if cache is not None else None
-        x, nc, aux = blk(kind, use_moe, params["tail"][i], x, positions,
-                         mode, c, cache_index, mla_absorbed)
+        x, nc, aux, sl = blk(kind, use_moe, params["tail"][i], x, positions,
+                             mode, c, cache_index, mla_absorbed)
         aux_total += aux
         new_cache["tail"].append(nc)
+        if sl is not None:
+            slots.append(sl)
 
     x = rmsnorm(x, params["final_norm"])
-    return x, (new_cache if cache is not None or mode == "prefill"
-               else None), aux_total
+    out = (x, (new_cache if cache is not None or mode == "prefill"
+               else None), aux_total)
+    if with_slots:
+        return out + (jnp.stack(slots) if slots else None,)
+    return out

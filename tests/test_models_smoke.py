@@ -97,6 +97,7 @@ def test_full_config_card_dims(arch):
         "qwen2-vl-2b": (28, 1536, 12, 2, 8960, 151936),
         "deepseek-v3-671b": (61, 7168, 128, 128, None, 129280),
         "deepseek-v2-236b": (60, 5120, 128, 128, None, 102400),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
         "stablelm-12b": (40, 5120, 32, 8, 13824, 100352),
         "command-r-35b": (40, 8192, 64, 8, 22528, 256000),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
@@ -113,9 +114,10 @@ def test_full_config_card_dims(arch):
         assert cfg.d_ff == ff
     if arch.startswith("deepseek"):
         assert cfg.kv_lora == 512
-        assert (cfg.n_experts, cfg.topk) == \
-            ((256, 8) if "v3" in arch else (160, 6))
-        assert cfg.moe_d_ff == (2048 if "v3" in arch else 1536)
+        assert (cfg.n_experts, cfg.topk, cfg.moe_d_ff) == {
+            "deepseek-v3-671b": (256, 8, 2048),
+            "deepseek-v2-236b": (160, 6, 1536),
+            "deepseek-v2-lite": (64, 6, 1408)}[arch]
     if arch == "falcon-mamba-7b":
         assert cfg.ssm_state == 16 and cfg.layer_pattern == ("mamba",)
     if arch == "recurrentgemma-9b":

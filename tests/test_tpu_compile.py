@@ -81,3 +81,44 @@ def test_round_programs_compile_for_v5e(one_chip, monkeypatch, program):
     fn = getattr(tr, f"_{program}")
     hlo = fn.lower(*args).compile().as_text()
     assert ("tpu_custom_call" in hlo) == (program == "sigma_all")
+
+
+@pytest.mark.parametrize("program", ["sigma_all", "local_grads"])
+def test_sequence_programs_compile_for_v5e(one_chip, monkeypatch, program):
+    """DeepSeek-V2-Lite's sigma and device-by-device gradient programs at
+    its published widths, one dense and one expert layer (8 of its 64
+    experts held, an eighth of the vocabulary): the held share's grouped
+    products lower for the TPU (``gmm``, and ``tgmm`` for the weights'
+    gradient)."""
+    from repro.configs import get_config
+    from repro.fed import rounds
+    from repro.models import init_model
+    from repro.models.control_lm import ControlTokenLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(rounds, "device_bytes", lambda: 1)
+    cfg = get_config("deepseek-v2-lite").scaled(
+        n_layers=2, vocab=12800, experts_held=(0, 8), dtype="float32")
+    K, d_hat, S = 2, 2, 128
+    sys_ = default_system(K=K, N=1, Q=2, D_hat=d_hat)
+    tr = FEELTrainer(sys_, None, ControlTokenLM(cfg), {"w": jnp.zeros(1)},
+                     FEELConfig(d_hat=d_hat))
+    assert tr._stream
+
+    def spec(x):
+        return _spec(x.shape, x.dtype, one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda k: init_model(k, cfg), jax.random.PRNGKey(0)))
+    x = _spec((K, d_hat, S), jnp.int32, one_chip)
+    labels = _spec((K, d_hat), jnp.int32, one_chip)
+    if program == "sigma_all":
+        lowered = tr._sigma_all.lower(params, x, labels)
+    else:
+        lowered = tr._local_grads.lower(
+            params, jax.tree.map(spec, sys_),
+            _spec((K,), jnp.float32, one_chip), x, labels,
+            _spec((K, d_hat), jnp.float32, one_chip))
+    hlo = lowered.compile().as_text()
+    assert "gmm" in hlo and "tpu_custom_call" in hlo
+    assert ("tgmm" in hlo) == (program == "local_grads")
