@@ -40,6 +40,7 @@ from ..core import cost as cost_mod
 from ..core import joint as joint_mod
 from ..core.types import RoundState, SystemParams
 from ..data.federated import FederatedDataset
+from ..models import moe as moe_mod
 from . import client as client_mod
 from . import faults as faults_mod
 from . import server as server_mod
@@ -660,8 +661,9 @@ class FEELTrainer:
         """The held experts' load of the round as the ``moe.load`` span's
         attributes: slots per layer and held expert of the scored
         sequences and of those with a nonzero eq. (19) weight (selected,
-        of a live device that uploaded), and the tokens behind each.
-        The counts come to the host here, under ``telemetry``."""
+        of a live device that uploaded), the tokens behind each, and the
+        row tile and operand dtype of the grouped products.  The counts
+        come to the host here, under ``telemetry``."""
         weighted = sel & (uploaded & self._ipw_live)[:, None]   # (K, D̂)
         grad = np.zeros(np.shape(sigma_slots), np.int64)
         if grad_slots is not None:
@@ -671,7 +673,9 @@ class FEELTrainer:
                        sigma_slots=np.asarray(sigma_slots).tolist(),
                        grad_slots=grad.tolist(),
                        tokens_scored=int(sel.size * seq_len),
-                       tokens_grad=int(weighted.sum() * seq_len)):
+                       tokens_grad=int(weighted.sum() * seq_len),
+                       **moe_mod.grouped_path(self.model.cfg,
+                                              sel.shape[1] * seq_len)):
             pass
 
     def _profile_once(self, name: str, stage: str, fn, args, tele,

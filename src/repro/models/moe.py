@@ -12,7 +12,10 @@ Two layers:
   experts' weights (the Pallas grouped matmul that ships with JAX,
   ``megablox.gmm``), then un-sorts and weights by the gate.  No token is
   dropped; what the absent experts would add is left out, as on a chip
-  of an expert-parallel deployment before its exchange.
+  of an expert-parallel deployment before its exchange.  The products
+  and their gradients take their operands as the backend's float32
+  ``jnp.dot`` would (``operand_dtype``) and return float32, in row
+  tiles the expected load of a held expert fills (``row_tile``).
 * ``moe_ffn``, the launch path's, below.
 
 TPU adaptation (DESIGN.md §2): dispatch is *capacity-based gather*
@@ -27,11 +30,13 @@ collectives the roofline analysis attributes to MoE.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas.ops.tpu import megablox
+# the kernels themselves: the package's ``gmm`` is its own custom VJP
+from jax.experimental.pallas.ops.tpu.megablox.ops import backend as kernels
 
 from .config import ArchConfig
 from .layers import init_dense, mlp
@@ -78,14 +83,81 @@ def _tile(n: int) -> int:
     return min(512, n)
 
 
-def _grouped(x: Array, w: Array, sizes: Array) -> Array:
+def row_tile(assignments: int, n_experts: int) -> int:
+    """The grouped products' row tile: the largest of 128, 256 and 512
+    that the expected rows of a held group (``assignments`` spread over
+    all ``n_experts`` the router chooses from) fill.  Each row tile
+    reads its group's whole weight block, so a wider tile does more
+    work per weight byte, until tiles straddling two groups waste it."""
+    expected = assignments // n_experts
+    return max([t for t in (256, 512) if t <= expected], default=128)
+
+
+def operand_dtype():
+    """What the grouped products feed the MXU: bfloat16 where a float32
+    ``jnp.dot`` runs as one bfloat16 pass with float32 accumulation (the
+    TPU at JAX's default matmul precision), else float32 (off the TPU,
+    or where ``jax_default_matmul_precision`` asks for more)."""
+    if jax.default_backend() != "tpu":
+        return jnp.float32
+    if jax.config.jax_default_matmul_precision not in (
+            None, "default", "bfloat16", "fastest"):
+        return jnp.float32
+    return jnp.bfloat16
+
+
+def grouped_path(cfg: ArchConfig, tokens: int) -> dict:
+    """Which grouped products a pass over ``tokens`` tokens runs: their
+    row tile and the dtype they feed the MXU."""
+    return {"row_tile": row_tile(tokens * cfg.topk, cfg.n_experts),
+            "operand_dtype": jnp.dtype(operand_dtype()).name}
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm(x: Array, w: Array, sizes: Array, tm: int, dtype) -> Array:
+    """``megablox``'s grouped product with operands rounded to ``dtype``
+    and float32 accumulation and output; the gradients likewise, each
+    product's tiles sized from its own widths."""
+    return _gmm_fwd(x, w, sizes, tm, dtype)[0]
+
+
+def _gmm_fwd(x, w, sizes, tm, dtype):
+    xl, wl = x.astype(dtype), w.astype(dtype)
+    y = kernels.gmm(xl, wl, sizes, jnp.float32,
+                    (tm, _tile(x.shape[1]), _tile(w.shape[2])),
+                    interpret=_interpret())
+    return y, (xl, wl, sizes)
+
+
+def _gmm_bwd(tm, dtype, res, dy):
+    xl, wl, sizes = res
+    dyl = dy.astype(dtype)
+    dx = kernels.gmm(dyl, wl, sizes, jnp.float32,
+                     (tm, _tile(wl.shape[2]), _tile(wl.shape[1])),
+                     transpose_rhs=True, interpret=_interpret())
+    dw = kernels.tgmm(xl.swapaxes(0, 1), dyl, sizes, jnp.float32,
+                      (tm, _tile(wl.shape[1]), _tile(wl.shape[2])),
+                      num_actual_groups=wl.shape[0],
+                      interpret=_interpret())
+    return dx, dw, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _grouped(x: Array, w: Array, sizes: Array, tm: int,
+             dtype=None) -> Array:
     """Rows of x grouped by expert (``sizes`` rows each, the rest past
-    the groups) times each group's expert weights: (rows, w.shape[-1]).
-    Rows past the groups are not computed and read as garbage."""
-    tiling = (128, _tile(x.shape[1]), _tile(w.shape[2]))
-    return megablox.gmm(x, w, sizes, preferred_element_type=x.dtype,
-                        tiling=tiling,
-                        interpret=jax.default_backend() != "tpu")
+    the groups) times each group's expert weights: (rows, w.shape[-1])
+    in float32, fed to the MXU in ``dtype`` (``operand_dtype()`` where
+    None).  Rows past the groups are not computed and read as garbage."""
+    f32 = jnp.float32
+    return _gmm(x.astype(f32), w.astype(f32), sizes, tm,
+                dtype or operand_dtype()).astype(x.dtype)
 
 
 def held_expert_ffn(cfg: ArchConfig, p: dict, x: Array
@@ -111,16 +183,17 @@ def held_expert_ffn(cfg: ArchConfig, p: dict, x: Array
     group = jnp.where(held, local, Eh).reshape(-1)    # (G*K,)
     order = jnp.argsort(group, stable=True)
     sizes = jnp.sum(slots, axis=0)
-    rows = -(-G * K // 128) * 128                     # 128-row tiles
+    tm = row_tile(G * K, cfg.n_experts)
+    rows = -(-G * K // tm) * tm                       # whole row tiles
     pad = rows - G * K
     order = jnp.concatenate([order, jnp.full((pad,), G * K, order.dtype)])
     valid = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
     tok = jnp.minimum(order // K, G - 1)
     xs = jnp.where(valid, xf[tok], 0)
     act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
-    h = act(_grouped(xs, p["w_gate"], sizes)) \
-        * _grouped(xs, p["w_up"], sizes)
-    ys = jnp.where(valid, _grouped(h, p["w_down"], sizes), 0)
+    h = act(_grouped(xs, p["w_gate"], sizes, tm)) \
+        * _grouped(xs, p["w_up"], sizes, tm)
+    ys = jnp.where(valid, _grouped(h, p["w_down"], sizes, tm), 0)
     gate = jnp.concatenate([jnp.where(held, gates, 0.0).reshape(-1),
                             jnp.zeros((1,), gates.dtype)])[order]
     yf = jnp.zeros((G, d), x.dtype).at[tok].add(

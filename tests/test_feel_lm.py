@@ -24,6 +24,7 @@ from repro.models import init_model
 from repro.models.control_lm import ControlTokenLM
 from repro.models.layers import yarn_inv_freq, yarn_mscale
 from repro.models.mla import softmax_scale
+from repro.models import moe
 from repro.models.moe import held_expert_ffn, init_moe, route
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -104,6 +105,83 @@ def test_held_share_over_partial_tiles_matches_dense():
         np.testing.assert_allclose(np.asarray(g_held[k]),
                                    np.asarray(g_dense[k]),
                                    rtol=2e-4, atol=2e-4)
+
+
+def _rounded_dense(x, w, dy, sizes):
+    """Each group's rows times its weights, its input gradient and its
+    weights' gradient as dense products of bfloat16-rounded operands,
+    multiplied and summed in float32."""
+    def bf(a):
+        return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+    ys, dxs, dws, start = [], [], [], 0
+    for g, n in enumerate(sizes):
+        xg, dyg = bf(x[start:start + n]), bf(dy[start:start + n])
+        ys.append(jnp.dot(xg, bf(w[g]), precision=HIGHEST))
+        dxs.append(jnp.dot(dyg, bf(w[g]).T, precision=HIGHEST))
+        dws.append(jnp.dot(xg.T, dyg, precision=HIGHEST))
+        start += n
+    return jnp.concatenate(ys), jnp.concatenate(dxs), jnp.stack(dws)
+
+
+@pytest.mark.parametrize("tm", [128, 256])
+@pytest.mark.parametrize("name", ["w_gate", "w_up", "w_down"])
+def test_bfloat16_grouped_products_match_rounded_dense(tm, name):
+    """Fed bfloat16 (the TPU's path at JAX's default matmul precision),
+    the grouped product of each of the three weights, its input
+    gradient and its weights' gradient equal dense products of the same
+    rounded operands with float32 accumulation, all in float32: groups
+    over partial tiles, one group empty, widths past one 512 tile."""
+    cfg = _layer_cfg(d_model=640, moe_d_ff=704)
+    w = init_moe(jax.random.PRNGKey(5), cfg, jnp.float32)[name][:4]
+    sizes = [tm + 37, 0, 2 * tm - 5, 90]
+    n = sum(sizes)
+    rows = -(-n // tm) * tm
+    kx, kdy = jax.random.split(jax.random.PRNGKey(6))
+    x = jax.random.normal(kx, (rows, w.shape[1]))
+    dy = jnp.where(jnp.arange(rows)[:, None] < n,
+                   jax.random.normal(kdy, (rows, w.shape[2])), 0.0)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+
+    def grouped(dtype):
+        y, vjp = jax.vjp(
+            lambda a, b: moe._grouped(a, b, group_sizes, tm, dtype), x, w)
+        return (y,) + vjp(dy)
+
+    y, dx, dw = grouped(jnp.bfloat16)
+    assert y.dtype == dx.dtype == dw.dtype == jnp.float32
+    want = _rounded_dense(x, w, dy, sizes)
+    for got, ref in zip((y[:n], dx[:n], dw), want):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(dw[1]), 0.0)
+    # float32 operands read differently: the check sees the rounding
+    y32 = grouped(jnp.float32)[0]
+    assert float(jnp.max(jnp.abs(y32[:n] - want[0]))) > 1e-3
+
+
+@pytest.mark.parametrize("G,K,E,tm", [
+    (4096, 6, 64, 256),   # dsv2lite_k10: 8 sequences of 512, top-6 of 64
+    (8192, 6, 64, 512),
+    (2 * 16, 3, 8, 128),  # the layer tests here
+    (2 * 8, 3, 8, 128),   # the trainer tests here
+    (2 * 128, 6, 64, 128),  # the v5e compile test's pass
+])
+def test_row_tile_follows_the_expected_expert_load(G, K, E, tm):
+    assert moe.row_tile(G * K, E) == tm
+
+
+def test_operand_dtype_follows_backend_and_precision(monkeypatch):
+    """float32 off the TPU; on it bfloat16 at JAX's default matmul
+    precision, float32 where the setting asks for float32 or more."""
+    assert moe.operand_dtype() == jnp.float32
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe.operand_dtype() == jnp.bfloat16
+    with jax.default_matmul_precision("bfloat16"):
+        assert moe.operand_dtype() == jnp.bfloat16
+    for asks in ("float32", "highest", "tensorfloat32"):
+        with jax.default_matmul_precision(asks):
+            assert moe.operand_dtype() == jnp.float32
 
 
 def test_share_drops_no_token_under_a_skewed_router():
@@ -281,6 +359,7 @@ def test_moe_load_span_counts_the_routed_slots(monkeypatch):
         and tr._ipw_live[k]) or m.n_uploaded < 4
     assert np.sum(a["grad_slots"]) <= a["tokens_grad"] * 3
     assert np.sum(a["grad_slots"]) <= np.sum(a["sigma_slots"])
+    assert (a["row_tile"], a["operand_dtype"]) == (128, "float32")
 
 
 def _params0(tr):

@@ -11,6 +11,7 @@ only one process may hold the TPU library, and every test worker
 imports every test file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,7 +90,8 @@ def test_sequence_programs_compile_for_v5e(one_chip, monkeypatch, program):
     its published widths, one dense and one expert layer (8 of its 64
     experts held, an eighth of the vocabulary): the held share's grouped
     products lower for the TPU (``gmm``, and ``tgmm`` for the weights'
-    gradient)."""
+    gradient), fed bfloat16, with the held weights cast outside every
+    while loop."""
     from repro.configs import get_config
     from repro.fed import rounds
     from repro.models import init_model
@@ -122,3 +124,52 @@ def test_sequence_programs_compile_for_v5e(one_chip, monkeypatch, program):
     hlo = lowered.compile().as_text()
     assert "gmm" in hlo and "tpu_custom_call" in hlo
     assert ("tgmm" in hlo) == (program == "local_grads")
+    # the grouped products are fed bfloat16 rows and weights
+    operands = _grouped_operand_types(hlo)
+    assert len(operands) == (3 if program == "sigma_all" else 12)
+    assert all(t.startswith("bf16[") for pair in operands for t in pair)
+    # the held weights are cast once, not in a device's or layer's loop
+    d, f = cfg.d_model, cfg.moe_d_ff
+    weight_cast = re.compile(
+        rf"= bf16\[[\d,]*({d},{f}|{f},{d})\]\S* convert\(")
+    looped = [line for c in _while_bodies(hlo) for line in c
+              if weight_cast.search(line)]
+    assert not looped, looped
+
+
+def _computations(hlo: str) -> dict:
+    """The HLO text's computations: name -> instruction lines."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None and line.startswith("  "):
+            cur.append(line)
+    return comps
+
+
+def _grouped_operand_types(hlo: str) -> list:
+    """(rows, weights) types of each ``gmm``/``tgmm`` custom call: its
+    last two operands, after the scalar-prefetched group metadata."""
+    types = dict(re.findall(r"^\s+(?:ROOT )?%(\S+) = (\S+?)\{", hlo, re.M))
+    calls = re.findall(r"%t?gmm\.\d+ = \S+ custom-call\(([^)]*)\), "
+                       r"custom_call_target=\"tpu_custom_call\"", hlo)
+    return [tuple(types[o.split("%")[-1]] for o in args.split(",")[-2:])
+            for args in calls]
+
+
+def _while_bodies(hlo: str) -> list:
+    """The instruction lines of every while body and of every
+    computation a body calls, fused ones included."""
+    comps = _computations(hlo)
+    called = {c: set(re.findall(r"%([\w.\-]+)", "\n".join(lines)))
+              & set(comps) for c, lines in comps.items()}
+    todo = set(re.findall(r"body=%([\w.\-]+)", hlo))
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo |= called.get(c, set())
+    return [comps[c] for c in seen if c in comps]
